@@ -22,7 +22,9 @@ import subprocess
 import sys
 import textwrap
 
-from benchmarks.common import emit_bench_json, measure_fused_vs_host, record
+from benchmarks.common import (
+    cpu_child_env, emit_bench_json, measure_fused_vs_host, record,
+)
 from repro.core.distributed import ring_comm_elements
 
 SCRIPT = textwrap.dedent(
@@ -33,7 +35,6 @@ SCRIPT = textwrap.dedent(
     sys.path.insert(0, sys.argv[1])
     import numpy as np, jax, jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.core import compat
     mesh = jax.make_mesh((8,), ("data",))
     n, dims = int(sys.argv[2]), int(sys.argv[3])
     x = jnp.zeros((n, dims), jnp.float32)
@@ -45,7 +46,7 @@ SCRIPT = textwrap.dedent(
             return jax.lax.ppermute(e, "data", perm)
         return jax.lax.fori_loop(0, 7, body, v)
 
-    f = jax.jit(compat.shard_map(ring, mesh=mesh, in_specs=P("data"), out_specs=P("data")))
+    f = jax.jit(jax.shard_map(ring, mesh=mesh, in_specs=P("data"), out_specs=P("data")))
     f(x).block_until_ready()  # compile
     t0 = time.perf_counter()
     for _ in range(3):
@@ -118,8 +119,8 @@ def run(tiny: bool = False):
     for name, n, dims in (TINY_CELLS if tiny else FULL_CELLS):
         out = subprocess.run(
             [sys.executable, "-c", SCRIPT, src, str(n), str(dims)],
-            capture_output=True, text=True, timeout=600,
-            env={k: v for k, v in os.environ.items() if k != "XLA_FLAGS"},
+            # a CPU rehearsal on 8 simulated devices, not a chip measurement
+            capture_output=True, text=True, timeout=600, env=cpu_child_env(),
         )
         if out.returncode != 0:
             raise RuntimeError(f"ring subprocess failed:\n{out.stderr[-2000:]}")

@@ -17,12 +17,11 @@ def _make_tiles_loop(pts_sorted, tile_start, tile_len, tile_size, dim_block):
     """The pre-engine per-tile host loop, kept here as the baseline."""
     num_tiles = tile_start.shape[0]
     n = pts_sorted.shape[1]
-    n_pad = ((n + dim_block - 1) // dim_block) * dim_block
-    tiles = np.zeros((max(num_tiles, 1), tile_size, n_pad), dtype=np.float32)
+    tiles = np.zeros((max(num_tiles, 1), tile_size, n), dtype=np.float32)
     for i in range(num_tiles):
         s, l = int(tile_start[i]), int(tile_len[i])
-        tiles[i, :l, :n] = pts_sorted[s : s + l]
-    return tiles, tile_len.astype(np.int32)
+        tiles[i, :l] = pts_sorted[s : s + l]
+    return ops.block_dims(tiles, dim_block), tile_len.astype(np.int32)
 
 
 def run():

@@ -36,7 +36,7 @@ def run():
         t0 = time.perf_counter()
         counts, _ = ops.tile_counts(
             tiles, tlen, plan.pair_a, plan.pair_b,
-            eps=eps, dim_block=cfg.dim_block, shortc=True,
+            eps=eps, shortc=True,
         )
         t_kernel = time.perf_counter() - t0
 

@@ -56,7 +56,7 @@ def batch_times(d, eps, k, n_batches, tile_size=32, dim_block=32):
         t0 = time.perf_counter()
         ops.tile_counts(
             tiles, tlen, plan.pair_a[lo:hi], plan.pair_b[lo:hi],
-            eps=eps, dim_block=dim_block, shortc=True,
+            eps=eps, shortc=True,
         )
         times.append(time.perf_counter() - t0)
     return np.asarray(times)

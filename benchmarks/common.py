@@ -88,7 +88,8 @@ def span_summary(cap) -> Dict[str, object]:
 
 # Shared fused-vs-host measurement for the distributed engine (used by
 # bench_comm's contract row and bench_scaling's per-|p| rows).  Runs in a
-# subprocess: the 8-device flag must precede jax init.
+# CPU subprocess (``cpu_child_env``): the 8-device flag must precede jax
+# init.  Its times are CPU rehearsal times, not device measurements.
 _FUSED_VS_HOST_SCRIPT = textwrap.dedent(
     """
     import os
@@ -171,8 +172,7 @@ def measure_fused_vs_host(
             sys.executable, "-c", _FUSED_VS_HOST_SCRIPT, src,
             str(n), str(dims), ",".join(str(p) for p in workers),
         ],
-        capture_output=True, text=True, timeout=timeout,
-        env={k: v for k, v in os.environ.items() if k != "XLA_FLAGS"},
+        capture_output=True, text=True, timeout=timeout, env=cpu_child_env(),
     )
     if out.returncode != 0:
         raise RuntimeError(
@@ -193,6 +193,19 @@ def measure_fused_vs_host(
                  int(npairs))
             )
     return rows, prows
+
+
+def cpu_child_env() -> Dict[str, str]:
+    """Environment of a benchmark child process that simulates 8 devices.
+
+    Such children are CPU rehearsals of the multi-device paths, never a
+    device measurement: they pin ``JAX_PLATFORMS=cpu`` so that on a machine
+    with a chip they cannot contend for it with the parent, and they drop
+    the parent's ``XLA_FLAGS`` to set their own device count.
+    """
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env["JAX_PLATFORMS"] = "cpu"
+    return env
 
 
 def record(name: str, us_per_call: float, derived: str = ""):

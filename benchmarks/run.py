@@ -29,6 +29,9 @@ def main() -> None:
     ap.add_argument("--only", default=None, help="comma-separated module names")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     failed = []

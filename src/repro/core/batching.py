@@ -34,11 +34,9 @@ def estimate_result_size(
     plan,
     *,
     eps: float,
-    dim_block: int,
     backend: str,
     sample_frac: float = 0.01,
     seed: int = 0,
-    interpret: bool = True,
 ) -> int:
     """Estimated |R| from a sample of candidate tile pairs (counts only)."""
     p = plan.num_pairs
@@ -49,8 +47,7 @@ def estimate_result_size(
     sel = rng.choice(p, size=n_sample, replace=False)
     counts, _ = ops.tile_counts(
         tiles_pts, tile_len, plan.pair_a[sel], plan.pair_b[sel],
-        eps=eps, dim_block=dim_block, shortc=True, backend=backend,
-        interpret=interpret,
+        eps=eps, shortc=True, backend=backend,
     )
     return int(round(float(counts.sum()) * (p / n_sample)))
 

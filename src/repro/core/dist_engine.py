@@ -76,7 +76,6 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import batching as batching_mod
-from repro.core import compat
 from repro.core.distributed import ring_comm_elements, ring_scan
 from repro.core.engine import (
     _MAX_AUTO_GROW,
@@ -88,7 +87,8 @@ from repro.core.engine import (
 )
 from repro.core.grid import adjacent_cell_pairs, build_grid, pad_axis0
 from repro.core.partition import EntityPartition, assign_dynamic, make_partition
-from repro.core.reorder import variance_reorder
+from repro.core.reorder import apply_reorder, variance_reorder
+from repro.core.snapshot import GridSnapshot
 from repro.core.types import (
     EngineConfig,
     SelfJoinConfig,
@@ -186,14 +186,24 @@ class DistributedSelfJoinEngine:
         self.num_points, self.num_dims = self._pts.shape
         self.num_workers = int(num_workers)
 
+        # one REORDER frame for the whole dataset, shared by every shard: the
+        # f32 distance sums then add coordinates in the same order as the
+        # single-device engine, so results at the eps boundary do not depend
+        # on the partitioning
+        self._perm = None
+        if config.reorder and self.num_points:
+            _, self._perm = variance_reorder(self._pts, config.sample_frac)
+
         # dataset shards E_j: contiguous entity partition, unequal tails ok
         self.shard_bounds = np.linspace(
             0, self.num_points, self.num_workers + 1
         ).round().astype(np.int64)
         self.shards: List[SelfJoinEngine] = [
-            SelfJoinEngine(
-                self._pts[self.shard_bounds[j]:self.shard_bounds[j + 1]],
-                config,
+            SelfJoinEngine.from_snapshot(
+                GridSnapshot.build(
+                    self._pts[self.shard_bounds[j]:self.shard_bounds[j + 1]],
+                    config, perm=self._perm,
+                ),
                 engine_config,
             )
             for j in range(self.num_workers)
@@ -247,8 +257,8 @@ class DistributedSelfJoinEngine:
             self._batch_costs = costs
             return costs
         work = self._pts
-        if self.config.reorder:
-            work, _ = variance_reorder(self._pts, self.config.sample_frac)
+        if self._perm is not None:
+            work = apply_reorder(self._pts, self._perm)
         grid = build_grid(work, self.config.eps, self.config.k)
         ca, cb = adjacent_cell_pairs(grid)
         cell_cand = np.zeros(grid.num_cells, dtype=np.float64)
@@ -306,7 +316,8 @@ class DistributedSelfJoinEngine:
         cfg = self.config
         eng = self.engine_config or EngineConfig()
         t = cfg.tile_size
-        n_pad = self.shards[0].snapshot.n_pad
+        nb = self.shards[0].snapshot.num_dim_blocks
+        db = cfg.dim_block
 
         q_index = [self.worker_query_index(k) for k in range(p)]
         q_pts = [self._pts[idx] for idx in q_index]
@@ -344,14 +355,14 @@ class DistributedSelfJoinEngine:
         shard_sizes = np.diff(self.shard_bounds)
         max_sn = max(int(shard_sizes.max()) if shard_sizes.size else 0, 1)
 
-        qt = np.zeros((p, p, max_qt, t, n_pad), np.float32)
+        qt = np.zeros((p, p, max_qt, nb, t, db), np.float32)
         qstart = np.zeros((p, p, max_qt), np.int32)
         qlen = np.zeros((p, p, max_qt), np.int32)
         qord = np.full((p, p, max_nq), max_nq, np.int32)   # sentinel: dropped
         pq = np.zeros((p, p, n_chunks, chunk), np.int32)
         pd = np.zeros((p, p, n_chunks, chunk), np.int32)
         real = np.zeros((p, p, n_chunks), np.int32)
-        dt = np.zeros((p, max_dt, t, n_pad), np.float32)
+        dt = np.zeros((p, max_dt, nb, t, db), np.float32)
         dlen = np.zeros((p, max_dt), np.int32)
         # pairs-mode tables (DESIGN.md #7b): the same plans re-chunked at the
         # pairs granularity, plus the global-id decode tables -- the query
@@ -386,7 +397,7 @@ class DistributedSelfJoinEngine:
                 stats_candidates += qp.num_candidates
                 if qp.num_q_tiles:
                     tiles_kr, len_kr = ops.make_tiles(
-                        qp.q_sorted, qp.q_tile_start, qp.q_tile_len, t, cfg.dim_block
+                        qp.q_sorted, qp.q_tile_start, qp.q_tile_len, t, db
                     )
                     qt[k, r, : tiles_kr.shape[0]] = tiles_kr
                     qlen[k, r] = pad_axis0(len_kr, max_qt)
@@ -411,7 +422,6 @@ class DistributedSelfJoinEngine:
         axes_t = (self.axes,) if isinstance(self.axes, str) else tuple(self.axes)
         ax = axes_t if len(axes_t) > 1 else axes_t[0]
         backend = "pallas" if cfg.use_pallas else "jnp"
-        interpret = eng.interpret
         engine_self = self
 
         def local(qt, qstart, qlen, qord, pq, pd, real, dt, dlen, eps_in):
@@ -433,8 +443,7 @@ class DistributedSelfJoinEngine:
                         counts_sorted, jnp.zeros((), jnp.int32),
                         tiles, tlen, tstart,
                         pq[r, c], pd[r, c], real[r, c], eps_in,
-                        dim_block=cfg.dim_block, shortc=cfg.shortc,
-                        backend=backend, interpret=interpret,
+                        shortc=cfg.shortc, backend=backend,
                     )
                     return counts_sorted
 
@@ -444,8 +453,9 @@ class DistributedSelfJoinEngine:
                 # per-round q_order: q-sorted position -> worker-local slot
                 return counts_local.at[qord[r]].add(counts_sorted, mode="drop")
 
-            counts0 = compat.pvary(jnp.zeros(max_nq, jnp.int32), axes_t)
-            counts = ring_scan(axes_t, round_body, counts0, (dt, dlen))
+            counts = ring_scan(
+                axes_t, round_body, jnp.zeros(max_nq, jnp.int32), (dt, dlen)
+            )
             return counts[None]
 
         def pspec(arr):
@@ -481,8 +491,8 @@ class DistributedSelfJoinEngine:
                 counts_s, _ = ops.tile_counts(
                     np.concatenate([qt[k0, r0], dt[j0]], axis=0), len_c,
                     qp.pair_q[sel], qp.pair_d[sel] + max_qt,
-                    eps=eps, dim_block=cfg.dim_block, shortc=cfg.shortc,
-                    backend=backend, chunk=min(n_s, 512), interpret=interpret,
+                    eps=eps, shortc=cfg.shortc,
+                    backend=backend, chunk=min(n_s, 512),
                 )
                 cand_s = float(
                     (len_c[qp.pair_q[sel]].astype(np.float64)
@@ -500,28 +510,32 @@ class DistributedSelfJoinEngine:
             max(pairs_est, default=0), eng.pairs_headroom
         )
 
-        # tables go device-resident (with their ring sharding) at pack time:
-        # repeat joins and eps sweeps then transfer only the eps scalar
+        # tables go device-resident (with their ring sharding, row-major as
+        # every tile table, ops.row_major) at pack time: repeat joins and eps
+        # sweeps then transfer only the eps scalar
+        def place(a):
+            return ops.row_major(
+                jax.device_put(a, NamedSharding(self.mesh, pspec(a)))
+            )
+
         args = tuple(
-            jax.device_put(a, NamedSharding(self.mesh, pspec(a)))
-            for a in (qt, qstart, qlen, qord, pq, pd, real, dt, dlen)
+            place(a) for a in (qt, qstart, qlen, qord, pq, pd, real, dt, dlen)
         )
         fn = jax.jit(
-            compat.shard_map(
+            jax.shard_map(
                 local,
                 mesh=self.mesh,
                 in_specs=tuple(pspec(a) for a in args) + (P(),),
                 out_specs=P(ax, None),
-                # pallas_call has no replication rule; the program's outputs
-                # are device-varying by construction, so the check adds
-                # nothing here
-                check_rep=not cfg.use_pallas,
+                # off for both backends: pallas_call has no varying-axes rule,
+                # and the program's outputs are device-varying by
+                # construction, so the check would add nothing
+                check_vma=False,
             )
         )
 
         pairs_extra = tuple(
-            jax.device_put(a, NamedSharding(self.mesh, pspec(a)))
-            for a in (qog, pqp, pdp, realp, dstart, dord)
+            place(a) for a in (qog, pqp, pdp, realp, dstart, dord)
         )
         pairs_args = (
             args[0], args[1], args[2],                               # qt qstart qlen
@@ -566,18 +580,15 @@ class DistributedSelfJoinEngine:
                         return pairs_chunk_step(
                             *carry2, tiles, tlen, tstart, order,
                             pqp[r, c], pdp[r, c], realp[r, c], eps_in,
-                            hit_cap=hit_cap, dim_block=cfg.dim_block,
-                            backend=backend, interpret=interpret,
+                            hit_cap=hit_cap, backend=backend,
                         )
 
                     return jax.lax.fori_loop(0, n_chunks_p, chunk_body, carry)
 
                 carry0 = (
-                    compat.pvary(
-                        jnp.zeros((cap + hit_cap, 2), jnp.int32), axes_t
-                    ),
-                    compat.pvary(jnp.zeros((), jnp.int32), axes_t),
-                    compat.pvary(jnp.zeros((), jnp.int32), axes_t),
+                    jnp.zeros((cap + hit_cap, 2), jnp.int32),
+                    jnp.zeros((), jnp.int32),
+                    jnp.zeros((), jnp.int32),
                 )
                 buf, off, mh = ring_scan(
                     axes_t, round_body, carry0, (dt, dlen, dstart, dord)
@@ -585,12 +596,12 @@ class DistributedSelfJoinEngine:
                 return buf[None], off[None], mh[None]
 
             return jax.jit(
-                compat.shard_map(
+                jax.shard_map(
                     local_pairs,
                     mesh=self.mesh,
                     in_specs=pairs_in_specs,
                     out_specs=(P(ax, None, None), P(ax), P(ax)),
-                    check_rep=not cfg.use_pallas,
+                    check_vma=False,  # as for the count program
                 )
             )
 
@@ -828,8 +839,7 @@ class DistributedSelfJoinEngine:
                     counts_sorted, skipped,
                     tab.tiles, tab.tile_len, tab.tile_start,
                     pa, pb, real, jnp.float32(eps),
-                    dim_block=cfg.dim_block, shortc=shortc,
-                    backend=backend, interpret=eng.interpret,
+                    shortc=shortc, backend=backend,
                 )
             stats.num_device_dispatches += 1
         total = int(np.asarray(counts_sorted.sum()))
@@ -850,8 +860,7 @@ class DistributedSelfJoinEngine:
                         buf, offset, max_hits,
                         tab.tiles, tab.tile_len, tab.tile_start, tab.order,
                         pa, pb, real, jnp.float32(eps),
-                        hit_cap=hit_cap, dim_block=cfg.dim_block,
-                        backend=backend, interpret=eng.interpret,
+                        hit_cap=hit_cap, backend=backend,
                     )
                 stats.num_device_dispatches += 1
                 stats.num_chunks += 1

@@ -50,15 +50,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core import compat
-
 AxisNames = Union[str, Tuple[str, ...]]
 
 
 def _local_counts(q: jax.Array, e: jax.Array, eps2, row_block: int = 1024) -> jax.Array:
     """Per-q counts of e-points within eps (matmul form, row-blocked)."""
     nq = q.shape[0]
-    ne_norm = jnp.einsum("ij,ij->i", e, e)
+    hi = jax.lax.Precision.HIGHEST  # f32 stays f32 on a TPU's MXU
+    ne_norm = jnp.einsum("ij,ij->i", e, e, precision=hi)
 
     pad = (-nq) % row_block
     qp = jnp.pad(q, ((0, pad), (0, 0)))
@@ -66,9 +65,9 @@ def _local_counts(q: jax.Array, e: jax.Array, eps2, row_block: int = 1024) -> ja
 
     def one(qb):
         d2 = (
-            jnp.einsum("ij,ij->i", qb, qb)[:, None]
+            jnp.einsum("ij,ij->i", qb, qb, precision=hi)[:, None]
             + ne_norm[None, :]
-            - 2.0 * (qb @ e.T)
+            - 2.0 * jnp.matmul(qb, e.T, precision=hi)
         )
         return jnp.sum(d2 <= eps2, axis=1, dtype=jnp.int32)
 
@@ -93,15 +92,14 @@ def ring_scan(axes, body, carry, payload, *, num_rounds=None, overlap=False):
     body (the paper's Fig. 4 pipeline: transport overlaps compute; XLA
     schedules the independent ops concurrently on real hardware).
 
-    The carry must already be device-varying over ``axes`` where vma
-    tracking applies -- ``compat.pvary`` it before calling.
+    Under a ``shard_map`` with ``check_vma=True`` the carry must already be
+    device-varying over ``axes`` (``jax.lax.pcast(..., to="varying")``).
     """
     axes_t = (axes,) if isinstance(axes, str) else tuple(axes)
-    psize = compat.axis_size(axes_t)
-    perm = _ring_perm(psize)
+    perm = _ring_perm(jax.lax.axis_size(axes_t))
     rotate = functools.partial(
         jax.tree_util.tree_map,
-        lambda x: compat.ppermute(x, axes_t, perm),
+        lambda x: jax.lax.ppermute(x, axes_t, perm),
     )
 
     def step(r, state):
@@ -115,7 +113,7 @@ def ring_scan(axes, body, carry, payload, *, num_rounds=None, overlap=False):
             pl = rotate(pl)
         return carry, pl
 
-    n = psize if num_rounds is None else num_rounds
+    n = len(perm) if num_rounds is None else num_rounds
     carry, _ = jax.lax.fori_loop(0, n, step, (carry, payload))
     return carry
 
@@ -135,15 +133,15 @@ def make_ring_counts_fn(mesh: Mesh, axes: AxisNames, eps: float, row_block: int 
         def body(_, counts, e):
             return counts + _local_counts(q, e, eps2, row_block)
 
-        counts0 = jnp.zeros(q.shape[0], jnp.int32)
-        # the carry must be device-varying over the mesh axes on shard_map
-        # versions with vma tracking; a no-op on versions without (compat)
-        counts0 = compat.pvary(counts0, axes_t)
+        # the ring carry must be device-varying over the mesh axes
+        counts0 = jax.lax.pcast(
+            jnp.zeros(q.shape[0], jnp.int32), axes_t, to="varying"
+        )
         return ring_scan(axes_t, body, counts0, q)
 
     spec = P(axes_t if len(axes_t) > 1 else axes_t[0])
     return jax.jit(
-        compat.shard_map(local, mesh=mesh, in_specs=spec, out_specs=spec)
+        jax.shard_map(local, mesh=mesh, in_specs=spec, out_specs=spec)
     )
 
 

@@ -97,14 +97,14 @@ def _chunk_validity(tile_len, tile_start, pa, real, t):
 def count_chunk_step(
     counts_sorted,  # (N,) int32 running per-point counts, grid-sorted
     skipped_tot,    # ()  int32 running SHORTC skipped-block total
-    tiles,          # (num_tiles, T, n_pad) f32
+    tiles,          # (num_tiles, NB, T, DB) f32 (ops.make_tiles layout)
     tile_len,       # (num_tiles,) int32
     tile_start,     # (num_tiles,) int32
     pa, pb,         # (C,) int32 padded chunk of the candidate pair list
     real,           # () int32 valid prefix of the chunk
     eps,            # () f32 traced search radius
     *,
-    dim_block, shortc, backend, interpret,
+    shortc, backend,
 ):
     """One counts-mode chunk: evaluate + scatter-add, fully traceable.
 
@@ -114,11 +114,9 @@ def count_chunk_step(
     -- so nothing here may assume host-side (concrete) inputs.
     """
     counts, skipped = ops.eval_tile_pairs(
-        tiles, tile_len, pa, pb, eps,
-        dim_block=dim_block, shortc=shortc, backend=backend,
-        interpret=interpret,
+        tiles, tile_len, pa, pb, eps, shortc=shortc, backend=backend,
     )
-    t = tiles.shape[1]
+    t = tiles.shape[2]
     n = counts_sorted.shape[0]
     pair_valid, valid, idx = _chunk_validity(tile_len, tile_start, pa, real, t)
     idx = jnp.where(valid, idx, n)  # out-of-range -> dropped
@@ -138,7 +136,7 @@ def _count_chunk_traced(*args, **kwargs):
 
 
 _count_chunk_program = functools.partial(
-    jax.jit, static_argnames=("dim_block", "shortc", "backend", "interpret")
+    jax.jit, static_argnames=("shortc", "backend")
 )(_count_chunk_traced)
 
 
@@ -150,7 +148,7 @@ def pairs_chunk_step(
     point_order,    # (N,) int32 grid-sorted -> original id
     pa, pb, real, eps,
     *,
-    hit_cap, dim_block, backend, interpret,
+    hit_cap, backend,
 ):
     """One pairs-mode chunk: evaluate + compact into ``buf``, fully on device.
 
@@ -184,10 +182,9 @@ def pairs_chunk_step(
     """
     _, _, mask = ops.eval_tile_pairs(
         tiles, tile_len, pa, pb, eps,
-        dim_block=dim_block, shortc=True, backend=backend,
-        return_mask=True, interpret=interpret,
+        shortc=True, backend=backend, return_mask=True,
     )
-    t = tiles.shape[1]
+    t = tiles.shape[2]
     c = pa.shape[0]
     cap = buf.shape[0] - hit_cap
 
@@ -223,7 +220,7 @@ def _pairs_chunk_traced(*args, **kwargs):
 
 
 _pairs_chunk_program = functools.partial(
-    jax.jit, static_argnames=("hit_cap", "dim_block", "backend", "interpret")
+    jax.jit, static_argnames=("hit_cap", "backend")
 )(_pairs_chunk_traced)
 
 
@@ -277,7 +274,7 @@ class QueryPlanTables:
     nq: int                        # real query rows
     n_slots: int                   # padded query-position space (>= nq)
     qplan: QueryTilePlan           # the host-side plan (stats + q_order live here)
-    tiles: jax.Array               # (q_tile_rows + d_tile_rows, T, n_pad) f32
+    tiles: jax.Array               # (q_tile_rows + d_tile_rows, NB, T, DB) f32
     tile_len: jax.Array            # (q_tile_rows + d_tile_rows,) int32
     tile_start: jax.Array          # combined position space (B side + n_slots)
     order: jax.Array               # (n_slots + point_rows,) int32 position -> id
@@ -664,7 +661,7 @@ class SelfJoinEngine:
             tile_size=cfg.tile_size,
             dim_block=cfg.dim_block,
         )
-        tiles = jnp.concatenate([q_tiles, d_tiles], axis=0)
+        tiles = ops.row_major(jnp.concatenate([q_tiles, d_tiles], axis=0))
         tile_len = jnp.concatenate([jnp.asarray(q_len, jnp.int32), d_len])
         tile_start = jnp.concatenate(
             [jnp.asarray(q_start, jnp.int32), d_start + n_slots]
@@ -759,9 +756,7 @@ class SelfJoinEngine:
                         counts_sorted, skipped_tot,
                         tiles, tile_len, tile_start,
                         pa, pb, real, eps,
-                        dim_block=cfg.dim_block, shortc=shortc,
-                        backend=backend,
-                        interpret=eng.interpret,
+                        shortc=shortc, backend=backend,
                     )
                 stats.num_chunks += 1
                 stats.num_device_dispatches += 1
@@ -829,9 +824,7 @@ class SelfJoinEngine:
                         counts_sorted, skipped_tot,
                         tab.tiles, tab.tile_len, tab.tile_start,
                         pa, pb, real, eps,
-                        dim_block=cfg.dim_block, shortc=shortc,
-                        backend=backend,
-                        interpret=eng.interpret,
+                        shortc=shortc, backend=backend,
                     )
                 stats.num_chunks += 1
                 stats.num_device_dispatches += 1
@@ -910,8 +903,7 @@ class SelfJoinEngine:
                             buf, offset, max_hits,
                             tiles, tile_len, tile_start,
                             snap.point_order, pa, pb, real, eps,
-                            hit_cap=hit_cap, dim_block=cfg.dim_block,
-                            backend=backend, interpret=eng.interpret,
+                            hit_cap=hit_cap, backend=backend,
                         )
                     stats.num_chunks += 1
                     dispatches += 1
@@ -968,9 +960,8 @@ class SelfJoinEngine:
             dec, self.snapshot
         )
         est = batching_mod.estimate_result_size(
-            tiles, tile_len, plan, eps=eps,
-            dim_block=cfg.dim_block, backend=backend,
-            sample_frac=cfg.sample_frac, interpret=eng.interpret,
+            tiles, tile_len, plan, eps=eps, backend=backend,
+            sample_frac=cfg.sample_frac,
         )
         return batching_mod.suggest_pairs_capacity(est, eng.pairs_headroom)
 

@@ -88,8 +88,7 @@ def self_join_hostloop(
         config.tile_size, config.dim_block,
     )
     backend = "pallas" if config.use_pallas else "jnp"
-    n_pad = tiles_pts.shape[2]
-    nb_blocks = n_pad // config.dim_block
+    nb_blocks = tiles_pts.shape[1]
 
     counts_sorted = np.zeros(n_pts, dtype=np.int64)
     pairs_out: List[np.ndarray] = []
@@ -99,8 +98,7 @@ def self_join_hostloop(
     if return_pairs:
         # batching (Sec. 3.2.2): estimate |R|, derive n_b, process batch-wise
         est = batching_mod.estimate_result_size(
-            tiles_pts, tile_len, plan, eps=config.eps,
-            dim_block=config.dim_block, backend=backend,
+            tiles_pts, tile_len, plan, eps=config.eps, backend=backend,
             sample_frac=config.sample_frac,
         )
         n_b = batching_mod.compute_num_batches(
@@ -109,8 +107,7 @@ def self_join_hostloop(
         for lo, hi in batching_mod.batch_ranges(plan.num_pairs, n_b):
             pa, pb = plan.pair_a[lo:hi], plan.pair_b[lo:hi]
             for off, mask in ops.tile_mask(
-                tiles_pts, tile_len, pa, pb, eps=config.eps,
-                dim_block=config.dim_block, backend=backend,
+                tiles_pts, tile_len, pa, pb, eps=config.eps, backend=backend,
             ):
                 pp, ii, jj = np.nonzero(mask)
                 a_sorted = plan.tile_start[pa[off + pp]].astype(np.int64) + ii
@@ -130,8 +127,7 @@ def self_join_hostloop(
     else:
         counts_pair, skipped = ops.tile_counts(
             tiles_pts, tile_len, plan.pair_a, plan.pair_b,
-            eps=config.eps, dim_block=config.dim_block,
-            shortc=config.shortc, backend=backend,
+            eps=config.eps, shortc=config.shortc, backend=backend,
         )
         pa = plan.pair_a
         idx = plan.tile_start[pa].astype(np.int64)[:, None] + lane[None, :]

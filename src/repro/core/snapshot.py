@@ -98,7 +98,7 @@ class DenseTables:
     """Device-resident dense-tier twin of the snapshot's indexed tables."""
 
     plan: TilePlan
-    tiles: jax.Array          # (dense_rows, T, n_pad) f32, sequential layout
+    tiles: jax.Array          # (dense_rows, NB, T, DB) f32, sequential layout
     tile_len: jax.Array       # (dense_rows,) int32; padding rows are 0
     tile_start: jax.Array     # (dense_rows,) int32 into pts_sorted
     _chunk_cache: Dict[int, list] = dataclasses.field(default_factory=dict)
@@ -287,7 +287,7 @@ class GridSnapshot:
 
     @property
     def num_dim_blocks(self) -> int:
-        return self.tiles.shape[2] // self.config.dim_block
+        return self.n_pad // self.config.dim_block
 
     @property
     def data_bounds(self) -> Tuple[np.ndarray, np.ndarray]:
@@ -335,8 +335,10 @@ class GridSnapshot:
         understands), so they contribute nothing wherever a padded pair
         list references them.
         """
-        t = self.config.tile_size
-        tiles = np.zeros((num_tiles, t, self.n_pad), np.float32)
+        t, db = self.config.tile_size, self.config.dim_block
+        tiles = np.zeros(
+            (num_tiles, self.num_dim_blocks, t, db), np.float32
+        )
         tile_len = np.zeros(num_tiles, np.int32)
         if self.plan is not None and self.plan.num_tiles:
             real, lens = ops.make_tiles(
@@ -344,7 +346,7 @@ class GridSnapshot:
                 self.plan.tile_start,
                 self.plan.tile_len,
                 t,
-                self.config.dim_block,
+                db,
             )
             tiles[: real.shape[0]] = real
             tile_len[: lens.shape[0]] = lens

@@ -31,7 +31,7 @@ class SelfJoinConfig:
     sample_frac: float = 0.01    # variance / result-size sampling fraction (Sec. 4.2, 5.6)
     batch_size: int = 10**8      # b_s, result pairs per batch (paper Sec. 3.2.2)
     min_batches: int = 3         # n_b >= 3 (paper: >= 3 CUDA streams)
-    use_pallas: bool = False     # evaluate tiles with the Pallas kernel (interpret on CPU)
+    use_pallas: bool = False     # evaluate tiles with the Pallas kernels
     execution: str = "indexed"   # "indexed" | "dense" | "auto" tier dispatch;
                                  # "auto" picks by cost model (DESIGN.md #9)
 
@@ -63,7 +63,6 @@ class EngineConfig:
     auto_grow: bool = True       # on auto-sized overflow, regrow to the
                                  # measured |R| (known after the pass) and retry
     pairs_headroom: float = 2.0  # auto capacity = headroom * estimated |R|
-    interpret: bool = True       # run the Pallas kernel in interpret mode (CPU)
 
     def __post_init__(self):
         if self.count_chunk < 1 or self.pairs_chunk < 1:

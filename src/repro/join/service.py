@@ -167,7 +167,6 @@ class QueryService:
         self.buckets_used: Set[int] = set()
         self._trace_count = 0
 
-        cfg = index.config
         eng = index.engine.engine
         self._count_chunk = eng.count_chunk
         self._pairs_chunk = eng.pairs_chunk
@@ -190,8 +189,7 @@ class QueryService:
             counts, _ = count_chunk_step(
                 counts, jnp.zeros((), jnp.int32),
                 tiles, tile_len, tile_start, pa, pb, real, eps,
-                dim_block=cfg.dim_block, shortc=shortc,
-                backend=backend, interpret=eng.interpret,
+                shortc=shortc, backend=backend,
             )
             return counts
 
@@ -203,9 +201,7 @@ class QueryService:
             obs.event("service.trace", "trace", program="pairs")
             return pairs_chunk_step(
                 buf, offset, max_hits, tiles, tile_len, tile_start, order,
-                pa, pb, real, eps,
-                hit_cap=hit_cap, dim_block=cfg.dim_block,
-                backend=backend, interpret=eng.interpret,
+                pa, pb, real, eps, hit_cap=hit_cap, backend=backend,
             )
 
         # the delta/tombstone epilogue: one dense bipartite membership pass

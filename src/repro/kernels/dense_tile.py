@@ -22,16 +22,21 @@ full cross-product) tile-pair list as straight-line batched matmul work:
     tier's kNN eps-expansion loop stay on warm executables.
 
 Grid: ``(P, NB)`` -- P tile pairs x NB dimension blocks, dim-block axis
-minor so the partial-d2 scratch carries across blocks of one pair.
+minor so the partial-d2 scratch carries across blocks of one pair.  The
+table is the blocked ``(num_tiles, NB, T, dim_block)`` layout of
+``ops.make_tiles`` (see ``distance_tile.py`` for why).
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 
 def _kernel(
@@ -39,9 +44,9 @@ def _kernel(
     b_idx_ref,      # (P,) int32  scalar prefetch: B tile index per pair
     tile_len_ref,   # (num_tiles,) int32 scalar prefetch: valid points per tile
     eps2_ref,       # (1,) f32    scalar prefetch: runtime eps^2
-    a_ref,          # (1, T, DB) f32 VMEM: current dim block of the A tile
-    b_ref,          # (1, T, DB) f32 VMEM: current dim block of the B tile
-    counts_ref,     # (1, T) int32 out: per-A-point neighbour count
+    a_ref,          # (1, 1, T, DB) f32 VMEM: current dim block of the A tile
+    b_ref,          # (1, 1, T, DB) f32 VMEM: current dim block of the B tile
+    counts_ref,     # (1, 1, T) int32 out: per-A-point neighbour count
     d2_ref,         # (T, T) f32 VMEM scratch: partial squared distances
     *,
     num_blocks: int,
@@ -56,14 +61,15 @@ def _kernel(
     def _init():
         d2_ref[:, :] = jnp.zeros((t, t), jnp.float32)
 
-    a = a_ref[0]                                   # (T, DB)
-    b = b_ref[0]
+    a = a_ref[0, 0]                                # (T, DB)
+    b = b_ref[0, 0]
     na = jnp.sum(a * a, axis=1, keepdims=True)     # (T, 1)
     nb = jnp.sum(b * b, axis=1, keepdims=True)     # (T, 1)
     prod = jax.lax.dot_general(
         a,
         b,
         (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )                                              # (T, T) = a . b^T
     d2_ref[:, :] = d2_ref[:, :] + na + nb.T - 2.0 * prod
@@ -77,7 +83,7 @@ def _kernel(
         valid = (rows < la) & (cols < lb)
         d2 = jnp.maximum(d2_ref[:, :], 0.0)        # clamp the matmul identity
         within = (d2 <= eps2_ref[0]) & valid
-        counts_ref[0, :] = jnp.sum(within.astype(jnp.int32), axis=1)
+        counts_ref[0] = jnp.sum(within.astype(jnp.int32), axis=1)[None, :]
         if out_mask_ref is not None:
             out_mask_ref[0, :, :] = within.astype(jnp.int8)
 
@@ -91,19 +97,15 @@ def _mask_kernel(*refs, num_blocks, tile_size):
     )
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("dim_block", "interpret", "return_mask"),
-)
+@functools.partial(jax.jit, static_argnames=("interpret", "return_mask"))
 def dense_tile_distance(
-    tiles_pts: jax.Array,   # (num_tiles, T, n_pad) f32; n_pad % dim_block == 0
+    tiles_pts: jax.Array,   # (num_tiles, NB, T, DB) f32 (ops.make_tiles layout)
     tile_len: jax.Array,    # (num_tiles,) int32
     pair_a: jax.Array,      # (P,) int32
     pair_b: jax.Array,      # (P,) int32
     *,
     eps: float,
-    dim_block: int = 32,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     return_mask: bool = False,
 ):
     """Evaluate every listed tile pair densely (no SHORTC, clamped identity).
@@ -113,24 +115,23 @@ def dense_tile_distance(
     ``eps`` may be a python float or a traced f32 scalar (scalar-prefetch
     operand -- distinct eps values share one executable).  Returns
     ``counts (P, T) int32`` and, when ``return_mask``, also the per-pair
-    hit mask ``(P, T, T) int8``.
+    hit mask ``(P, T, T) int8``.  ``interpret`` defaults to the platform's
+    mode (``resolve_interpret``).
     """
-    num_tiles, t, n_pad = tiles_pts.shape
-    if n_pad % dim_block:
-        raise ValueError(f"n_pad={n_pad} not a multiple of dim_block={dim_block}")
-    nb = n_pad // dim_block
+    interpret = resolve_interpret(interpret)
+    _, nb, t, db = tiles_pts.shape
     p = pair_a.shape[0]
     eps2 = (jnp.asarray(eps, jnp.float32) ** 2).reshape(1)
 
     tile_spec_a = pl.BlockSpec(
-        (1, t, dim_block), lambda pp, jj, a_idx, b_idx, tl, e2: (a_idx[pp], 0, jj)
+        (1, 1, t, db), lambda pp, jj, a_idx, b_idx, tl, e2: (a_idx[pp], jj, 0, 0)
     )
     tile_spec_b = pl.BlockSpec(
-        (1, t, dim_block), lambda pp, jj, a_idx, b_idx, tl, e2: (b_idx[pp], 0, jj)
+        (1, 1, t, db), lambda pp, jj, a_idx, b_idx, tl, e2: (b_idx[pp], jj, 0, 0)
     )
-    counts_spec = pl.BlockSpec((1, t), lambda pp, jj, *_: (pp, 0))
+    counts_spec = pl.BlockSpec((1, 1, t), lambda pp, jj, *_: (pp, 0, 0))
 
-    out_shapes = [jax.ShapeDtypeStruct((p, t), jnp.int32)]
+    out_shapes = [jax.ShapeDtypeStruct((p, 1, t), jnp.int32)]
     out_specs = [counts_spec]
     if return_mask:
         out_shapes.append(jax.ShapeDtypeStruct((p, t, t), jnp.int8))
@@ -146,9 +147,11 @@ def dense_tile_distance(
         out_specs=out_specs,
         scratch_shapes=[pltpu.VMEM((t, t), jnp.float32)],
     )
-    return pl.pallas_call(
+    res = pl.pallas_call(
         body,
         grid_spec=grid_spec,
         out_shape=out_shapes,
         interpret=interpret,
     )(pair_a, pair_b, tile_len, eps2, tiles_pts, tiles_pts)
+    counts = res[0].reshape(p, t)
+    return (counts, res[1]) if return_mask else (counts,)

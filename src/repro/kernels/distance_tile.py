@@ -13,7 +13,11 @@ Alg. 1 lines 11-19) re-thought for the TPU (DESIGN.md #1):
     pairs are decided and the remaining MXU work is skipped via ``pl.when``;
   * tiles are fetched from HBM into VMEM by BlockSpec index maps driven by
     scalar-prefetched tile indices (the flat candidate work list produced by
-    ``repro.core.grid.build_tile_plan``);
+    ``repro.core.grid.build_tile_plan``).  The table is laid out
+    ``(num_tiles, NB, T, dim_block)`` (``ops.make_tiles``), so one dim block
+    of one tile is a full-extent ``(T, dim_block)`` block -- the Mosaic
+    tiling rule only accepts trailing block dims that are (8, 128) multiples
+    or the whole array extent, and ``dim_block < n_pad`` is neither;
   * ``eps`` is a *runtime* scalar, prefetched into SMEM alongside the tile
     indices (DESIGN.md #1.5): one compiled program serves every eps value,
     which is what lets ``SelfJoinEngine.query`` sweep eps without recompiling.
@@ -25,11 +29,14 @@ same pair and is reset at block 0.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 _NEG_LARGE = 3.0e38  # python float: becomes an inline literal, not a captured const
 
@@ -39,10 +46,10 @@ def _kernel(
     b_idx_ref,      # (P,) int32  scalar prefetch: B tile index per pair
     tile_len_ref,   # (num_tiles,) int32 scalar prefetch: valid points per tile
     eps2_ref,       # (1,) f32    scalar prefetch: runtime eps^2
-    a_ref,          # (1, T, DB) f32 VMEM: current dim block of the A tile
-    b_ref,          # (1, T, DB) f32 VMEM: current dim block of the B tile
-    counts_ref,     # (1, T) int32 out: per-A-point neighbour count
-    skipped_ref,    # (1, 1) int32 out: dim blocks skipped by SHORTC
+    a_ref,          # (1, 1, T, DB) f32 VMEM: current dim block of the A tile
+    b_ref,          # (1, 1, T, DB) f32 VMEM: current dim block of the B tile
+    counts_ref,     # (1, 1, T) int32 out: per-A-point neighbour count
+    skipped_ref,    # (1, 1, 1) int32 out: dim blocks skipped by SHORTC
     d2_ref,         # (T, T) f32 VMEM scratch: partial squared distances
     flags_ref,      # (2,) int32 SMEM scratch: [done, blocks_computed]
     *,
@@ -69,14 +76,15 @@ def _kernel(
 
     @pl.when(flags_ref[0] == 0)
     def _accumulate():
-        a = a_ref[0]                                   # (T, DB)
-        b = b_ref[0]
+        a = a_ref[0, 0]                                # (T, DB)
+        b = b_ref[0, 0]
         na = jnp.sum(a * a, axis=1, keepdims=True)     # (T, 1)
         nb = jnp.sum(b * b, axis=1, keepdims=True)     # (T, 1)
         prod = jax.lax.dot_general(
             a,
             b,
             (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         )                                              # (T, T) = a . b^T
         d2_ref[:, :] = d2_ref[:, :] + na + nb.T - 2.0 * prod
@@ -89,8 +97,9 @@ def _kernel(
     @pl.when(j == num_blocks - 1)
     def _finalize():
         within = (d2_ref[:, :] <= eps2) & valid
-        counts_ref[0, :] = jnp.sum(within.astype(jnp.int32), axis=1)
-        skipped_ref[0, 0] = num_blocks - flags_ref[1]
+        counts_ref[0] = jnp.sum(within.astype(jnp.int32), axis=1)[None, :]
+        # a vector store: Mosaic refuses scalar stores into VMEM
+        skipped_ref[0] = jnp.full((1, 1), num_blocks - flags_ref[1], jnp.int32)
         if out_mask_ref is not None:
             out_mask_ref[0, :, :] = within.astype(jnp.int8)
 
@@ -104,19 +113,15 @@ def _mask_kernel(*refs, num_blocks, tile_size):
     )
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("dim_block", "interpret", "return_mask"),
-)
+@functools.partial(jax.jit, static_argnames=("interpret", "return_mask"))
 def tile_pair_distance(
-    tiles_pts: jax.Array,   # (num_tiles, T, n_pad) f32; n_pad % dim_block == 0
+    tiles_pts: jax.Array,   # (num_tiles, NB, T, DB) f32 (ops.make_tiles layout)
     tile_len: jax.Array,    # (num_tiles,) int32
     pair_a: jax.Array,      # (P,) int32
     pair_b: jax.Array,      # (P,) int32
     *,
     eps: float,
-    dim_block: int = 32,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     return_mask: bool = False,
 ):
     """Evaluate all candidate tile pairs.
@@ -125,26 +130,25 @@ def tile_pair_distance(
     the kernel as a scalar-prefetch operand, so distinct eps values share one
     executable.  Returns ``(counts (P,T) int32, skipped (P,1) int32)`` and,
     when ``return_mask``, also the per-pair boolean mask ``(P, T, T) int8``.
+    ``interpret`` defaults to the platform's mode (``resolve_interpret``).
     """
-    num_tiles, t, n_pad = tiles_pts.shape
-    if n_pad % dim_block:
-        raise ValueError(f"n_pad={n_pad} not a multiple of dim_block={dim_block}")
-    nb = n_pad // dim_block
+    interpret = resolve_interpret(interpret)
+    _, nb, t, db = tiles_pts.shape
     p = pair_a.shape[0]
     eps2 = (jnp.asarray(eps, jnp.float32) ** 2).reshape(1)
 
     tile_spec_a = pl.BlockSpec(
-        (1, t, dim_block), lambda pp, jj, a_idx, b_idx, tl, e2: (a_idx[pp], 0, jj)
+        (1, 1, t, db), lambda pp, jj, a_idx, b_idx, tl, e2: (a_idx[pp], jj, 0, 0)
     )
     tile_spec_b = pl.BlockSpec(
-        (1, t, dim_block), lambda pp, jj, a_idx, b_idx, tl, e2: (b_idx[pp], 0, jj)
+        (1, 1, t, db), lambda pp, jj, a_idx, b_idx, tl, e2: (b_idx[pp], jj, 0, 0)
     )
-    counts_spec = pl.BlockSpec((1, t), lambda pp, jj, *_: (pp, 0))
-    skip_spec = pl.BlockSpec((1, 1), lambda pp, jj, *_: (pp, 0))
+    counts_spec = pl.BlockSpec((1, 1, t), lambda pp, jj, *_: (pp, 0, 0))
+    skip_spec = pl.BlockSpec((1, 1, 1), lambda pp, jj, *_: (pp, 0, 0))
 
     out_shapes = [
-        jax.ShapeDtypeStruct((p, t), jnp.int32),
-        jax.ShapeDtypeStruct((p, 1), jnp.int32),
+        jax.ShapeDtypeStruct((p, 1, t), jnp.int32),
+        jax.ShapeDtypeStruct((p, 1, 1), jnp.int32),
     ]
     out_specs = [counts_spec, skip_spec]
     if return_mask:
@@ -164,9 +168,11 @@ def tile_pair_distance(
             pltpu.SMEM((2,), jnp.int32),
         ],
     )
-    return pl.pallas_call(
+    res = pl.pallas_call(
         body,
         grid_spec=grid_spec,
         out_shape=out_shapes,
         interpret=interpret,
     )(pair_a, pair_b, tile_len, eps2, tiles_pts, tiles_pts)
+    counts, skipped = res[0].reshape(p, t), res[1].reshape(p, 1)
+    return (counts, skipped, res[2]) if return_mask else (counts, skipped)

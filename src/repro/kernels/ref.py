@@ -10,6 +10,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+_HIGHEST = jax.lax.Precision.HIGHEST  # f32 contractions stay f32 on a TPU
+
 
 def matmul_sqdist(a: jax.Array, b: jax.Array) -> jax.Array:
     """Clamped matmul-form squared distances (the dense tier's identity).
@@ -25,7 +27,7 @@ def matmul_sqdist(a: jax.Array, b: jax.Array) -> jax.Array:
     b = b.astype(jnp.float32)
     na = jnp.sum(a * a, axis=-1)[..., :, None]
     nb = jnp.sum(b * b, axis=-1)[..., None, :]
-    prod = jnp.einsum("...in,...jn->...ij", a, b)
+    prod = jnp.einsum("...in,...jn->...ij", a, b, precision=_HIGHEST)
     return jnp.maximum(na + nb - 2.0 * prod, 0.0)
 
 
@@ -36,11 +38,11 @@ def direct_sqdist(a: jax.Array, b: jax.Array) -> jax.Array:
     rounding path; never negative by construction).
     """
     diff = a.astype(jnp.float32)[..., :, None, :] - b.astype(jnp.float32)[..., None, :, :]
-    return jnp.einsum("...ijn,...ijn->...ij", diff, diff)
+    return jnp.einsum("...ijn,...ijn->...ij", diff, diff, precision=_HIGHEST)
 
 
 def ref_tile_counts(
-    tiles_pts: jax.Array,   # (num_tiles, T, n) float32, zero-padded
+    tiles_pts: jax.Array,   # (num_tiles, NB, T, DB) float32 (ops.make_tiles)
     tile_len: jax.Array,    # (num_tiles,) int32
     pair_a: jax.Array,      # (P,) int32
     pair_b: jax.Array,      # (P,) int32
@@ -72,12 +74,16 @@ def ref_tile_mask(
     pair_b: jax.Array,
     eps: float,
 ) -> jax.Array:
-    """Boolean (P, T, T): pair (i, j) within eps and both lanes valid."""
-    t = tiles_pts.shape[1]
-    a = tiles_pts[pair_a]            # (P, T, n)
+    """Boolean (P, T, T): pair (i, j) within eps and both lanes valid.
+
+    ``tiles_pts`` is the blocked ``(num_tiles, NB, T, DB)`` table; the dim
+    blocks are summed like any other coordinate axis.
+    """
+    t = tiles_pts.shape[2]
+    a = tiles_pts[pair_a]            # (P, NB, T, DB)
     b = tiles_pts[pair_b]
-    diff = a[:, :, None, :] - b[:, None, :, :]
-    d2 = jnp.einsum("pijn,pijn->pij", diff, diff)
+    diff = a[:, :, :, None, :] - b[:, :, None, :, :]
+    d2 = jnp.einsum("pkijd,pkijd->pij", diff, diff, precision=_HIGHEST)
     la = tile_len[pair_a]            # (P,)
     lb = tile_len[pair_b]
     rows = jnp.arange(t, dtype=jnp.int32)

@@ -24,7 +24,6 @@ import jax          # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
-from repro.core import compat  # noqa: E402
 from repro.core.distributed import ring_scan  # noqa: E402
 from repro.launch.mesh import make_production_mesh, mesh_desc  # noqa: E402
 from repro.roofline import roofline_terms  # noqa: E402
@@ -57,7 +56,9 @@ def ring_fn(mesh, axes, eps, *, variant="base", row_block=2048):
         def body(_, counts, e):
             return counts + local_counts(q, e)
 
-        counts0 = compat.pvary(jnp.zeros(q.shape[0], jnp.int32), axes_t)
+        counts0 = jax.lax.pcast(
+            jnp.zeros(q.shape[0], jnp.int32), axes_t, to="varying"
+        )
         # overlap variant: ring_scan issues round r+1's permute before round
         # r's body -- paper Fig. 4's pipeline, at ring scale
         return ring_scan(
@@ -65,7 +66,7 @@ def ring_fn(mesh, axes, eps, *, variant="base", row_block=2048):
         )
 
     spec = P(axes_t if len(axes_t) > 1 else axes_t[0])
-    return jax.jit(compat.shard_map(body_fn, mesh=mesh, in_specs=spec, out_specs=spec))
+    return jax.jit(jax.shard_map(body_fn, mesh=mesh, in_specs=spec, out_specs=spec))
 
 
 def run_cell(points, dims, eps, multi_pod, variant, row_block=2048):

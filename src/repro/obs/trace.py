@@ -12,9 +12,8 @@ many were lost, so a runaway request stream can never exhaust host memory.
 
 ``to_chrome_trace()`` exports the buffer in Chrome-trace / Perfetto JSON
 (``chrome://tracing``, https://ui.perfetto.dev).  ``enable(jax_bridge=True)``
-additionally opens a ``jax.profiler.TraceAnnotation`` around every span (via
-the ``repro.core.compat.trace_annotation`` shim, a no-op when the running
-jax lacks the profiler API) so obs spans line up with XLA device traces.
+additionally opens a ``jax.profiler.TraceAnnotation`` around every span so
+obs spans line up with XLA device traces.
 
 This module deliberately imports nothing from ``repro.core`` at module
 scope: the engine imports ``repro.obs``, and an eager core import here
@@ -97,18 +96,16 @@ def enable(capacity: int = DEFAULT_CAPACITY, *, jax_bridge: bool = False) -> Non
     """Start recording into a fresh ring buffer of ``capacity`` events.
 
     ``jax_bridge=True`` wraps every span in a ``jax.profiler``
-    ``TraceAnnotation`` (no-op where unavailable) so obs spans appear in
-    XLA profiler timelines too.
+    ``TraceAnnotation`` so obs spans appear in XLA profiler timelines too.
     """
     if capacity < 1:
         raise ValueError(f"capacity must be >= 1, got {capacity}")
     bridge = None
     if jax_bridge:
-        # Lazy: avoids repro.core <-> repro.obs import cycles and keeps the
-        # default path jax-free.
-        from repro.core.compat import trace_annotation
+        # Lazy: keeps the default path jax-free.
+        import jax
 
-        bridge = trace_annotation
+        bridge = jax.profiler.TraceAnnotation
     with _state.lock:
         _state.capacity = int(capacity)
         _state.buf = []

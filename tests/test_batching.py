@@ -37,7 +37,7 @@ def test_estimate_within_factor_of_truth():
         grid.pts_sorted, plan.tile_start, plan.tile_len, 16, 8
     )
     est = estimate_result_size(
-        tiles, tlen, plan, eps=eps, dim_block=8, backend="jnp",
+        tiles, tlen, plan, eps=eps, backend="jnp",
         sample_frac=0.2,
     )
     truth = self_join(d, SelfJoinConfig(eps=eps, k=4, tile_size=16,

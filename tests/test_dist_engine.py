@@ -73,6 +73,21 @@ def test_dist_engine_single_worker_equals_engine():
     assert np.array_equal(de.count().counts, SelfJoinEngine(d, CFG).count().counts)
 
 
+def test_dist_engine_shards_share_the_dataset_reorder_frame():
+    """Every shard uses the whole dataset's REORDER permutation, so shard
+    distances sum coordinates in the single-device engine's order."""
+    rng = np.random.default_rng(0)
+    # each quarter of the rows has a different widest dimension
+    d = rng.random((800, 6)).astype(np.float32) * 0.1
+    for j in range(4):
+        d[200 * j : 200 * (j + 1), j] *= 5 + j
+    de = DistributedSelfJoinEngine(d, SelfJoinConfig(eps=0.05, k=3), num_workers=4)
+    want = SelfJoinEngine(d, SelfJoinConfig(eps=0.05, k=3))._perm
+    for shard in de.shards:
+        assert np.array_equal(shard._perm, want)
+    assert np.array_equal(de.count().counts, brute_counts(d, 0.05))
+
+
 def test_dist_engine_dynamic_assignment_parity_and_balance():
     d = exponential_dataset(800, 16, seed=9)
     truth = brute_counts(d, CFG.eps)

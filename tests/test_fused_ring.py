@@ -80,8 +80,8 @@ def test_fused_edge_cases_one_device(kind, n, dims, eps):
 
 
 def test_fused_pallas_backend_parity():
-    # pallas_call has no shard_map replication rule: the fused program must
-    # disable rep-checking for this backend (compat.shard_map check_rep)
+    # pallas_call has no shard_map varying-axes rule: the fused program must
+    # run with the check off for this backend (check_vma=False)
     import dataclasses
 
     d = make_dataset("exponential", 180, 16, seed=9)

@@ -1,15 +1,16 @@
 """Pallas distance-tile kernel vs the pure-jnp oracle (interpret mode).
 
 Shape/dtype sweep per the deliverable: tile sizes, dimension counts (with
-padding), dim-block splits; exactness via 1/64-quantized coordinates (all
-squared distances exactly representable in fp32 in both the direct and the
-matmul formulation).
+padding), dim-block splits (the blocked ``(num_tiles, NB, T, DB)`` layout of
+``ops.block_dims``); exactness via 1/64-quantized coordinates (all squared
+distances exactly representable in fp32 in both the direct and the matmul
+formulation).
 """
 import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels import ops
+from repro.kernels import ops, resolve_interpret
 from repro.kernels.distance_tile import tile_pair_distance
 from repro.kernels.ref import ref_tile_counts, ref_tile_mask
 
@@ -30,10 +31,11 @@ def _mk(num_tiles, t, n, seed, quantize=True):
 @pytest.mark.parametrize("n,db", [(8, 8), (16, 8), (32, 16), (64, 32)])
 def test_kernel_counts_match_ref(t, n, db):
     pts, lens, pa, pb = _mk(6, t, n, seed=t * 100 + n)
+    pts = ops.block_dims(pts, db)
     eps = 0.31
     counts, skipped = tile_pair_distance(
         jnp.asarray(pts), jnp.asarray(lens), jnp.asarray(pa), jnp.asarray(pb),
-        eps=eps, dim_block=db, interpret=True,
+        eps=eps, interpret=True,
     )
     ref = ref_tile_counts(jnp.asarray(pts), jnp.asarray(lens),
                           jnp.asarray(pa), jnp.asarray(pb), eps)
@@ -44,10 +46,11 @@ def test_kernel_counts_match_ref(t, n, db):
 @pytest.mark.parametrize("t", [8, 16])
 def test_kernel_mask_matches_ref(t):
     pts, lens, pa, pb = _mk(5, t, 16, seed=t)
+    pts = ops.block_dims(pts, 8)
     eps = 0.4
     _, _, mask = tile_pair_distance(
         jnp.asarray(pts), jnp.asarray(lens), jnp.asarray(pa), jnp.asarray(pb),
-        eps=eps, dim_block=8, interpret=True, return_mask=True,
+        eps=eps, interpret=True, return_mask=True,
     )
     ref = ref_tile_mask(jnp.asarray(pts), jnp.asarray(lens),
                         jnp.asarray(pa), jnp.asarray(pb), eps)
@@ -64,8 +67,8 @@ def test_kernel_shortcircuit_skips_far_tiles():
     pa = np.array([0, 0, 1], np.int32)
     pb = np.array([0, 1, 1], np.int32)
     counts, skipped = tile_pair_distance(
-        jnp.asarray(pts), jnp.asarray(lens), jnp.asarray(pa), jnp.asarray(pb),
-        eps=0.05, dim_block=8, interpret=True,
+        jnp.asarray(ops.block_dims(pts, 8)), jnp.asarray(lens),
+        jnp.asarray(pa), jnp.asarray(pb), eps=0.05, interpret=True,
     )
     c = np.asarray(counts)
     s = np.asarray(skipped)[:, 0]
@@ -86,7 +89,7 @@ def test_kernel_matches_jnp_backend_exactly():
     )
     for backend in ("jnp", "pallas"):
         c, s = ops.tile_counts(
-            tiles, lens32, pa, pb, eps=0.25, dim_block=8,
+            tiles, lens32, pa, pb, eps=0.25,
             shortc=True, backend=backend, chunk=16,
         )
         if backend == "jnp":
@@ -100,16 +103,46 @@ def test_unquantized_f32_tolerance():
     """Unquantized coords: matmul vs direct form may differ only at the
     eps boundary; counts must agree when no distance is within 1e-5 of eps."""
     rng = np.random.default_rng(3)
-    pts = rng.random((4, 8, 16), dtype=np.float32)
+    pts = ops.block_dims(rng.random((4, 8, 16), dtype=np.float32), 16)
     lens = np.full(4, 8, np.int32)
     pa = np.array([0, 1, 2], np.int32)
     pb = np.array([1, 2, 3], np.int32)
     eps = 0.437  # generic value; boundary ties have measure ~0
     counts, _ = tile_pair_distance(
         jnp.asarray(pts), jnp.asarray(lens), jnp.asarray(pa), jnp.asarray(pb),
-        eps=eps, dim_block=16, interpret=True,
+        eps=eps, interpret=True,
     )
     ref = ref_tile_counts(jnp.asarray(pts), jnp.asarray(lens),
                           jnp.asarray(pa), jnp.asarray(pb), eps)
     diff = np.abs(np.asarray(counts) - np.asarray(ref)).sum()
     assert diff <= 2  # allow boundary straddle only
+
+
+@pytest.mark.parametrize(
+    "platform,requested,want",
+    [("cpu", None, True), ("tpu", None, False), ("cpu", False, False),
+     ("tpu", False, False), ("cpu", True, True)],
+)
+def test_interpret_resolves_from_platform(platform, requested, want):
+    """The interpreter runs on the CPU only; a TPU always compiles."""
+    assert resolve_interpret(requested, platform) is want
+
+
+def test_interpret_request_on_tpu_is_an_error():
+    with pytest.raises(ValueError, match="interpret"):
+        resolve_interpret(True, "tpu")
+
+
+@pytest.mark.parametrize("n,db", [(16, 32), (18, 8), (90, 32)])
+def test_block_dims_layout(n, db):
+    """One dim block of one tile is a contiguous (T, db) slab; padding is 0."""
+    x = np.random.default_rng(n).random((3, 8, n)).astype(np.float32)
+    got = ops.block_dims(x, db)
+    nb = -(-n // db)
+    assert got.shape == (3, nb, 8, db)
+    flat = np.swapaxes(got, 1, 2).reshape(3, 8, nb * db)
+    np.testing.assert_array_equal(flat[:, :, :n], x)
+    assert not flat[:, :, n:].any()
+    np.testing.assert_array_equal(
+        np.asarray(ops.block_dims(jnp.asarray(x), db)), got
+    )

@@ -180,7 +180,7 @@ def test_capacity_estimate_never_underallocates(d, eps):
     eng = SelfJoinEngine(d, cfg)
     est = batching.estimate_result_size(
         np.asarray(eng._tiles), np.asarray(eng._tile_len), eng.plan,
-        eps=eps, dim_block=8, backend="jnp", sample_frac=1.0,
+        eps=eps, backend="jnp", sample_frac=1.0,
     )
     true_r = int(brute_counts(d, eps).sum())
     assert est >= true_r
