@@ -934,12 +934,13 @@ class SelfJoinEngine:
                 )
             break
 
-        pairs = np.asarray(buf[:num])
-        counts = np.asarray(
-            _counts_from_pairs(
-                jnp.zeros(snap.num_points, jnp.int32), buf, offset
-            )
-        ).astype(np.int64)
+        with obs.span("engine.pairs.fetch", "transfer", num=num):
+            pairs = np.asarray(buf[:num])
+            counts = np.asarray(
+                _counts_from_pairs(
+                    jnp.zeros(snap.num_points, jnp.int32), buf, offset
+                )
+            ).astype(np.int64)
         stats.num_results = int(counts.sum())
         stats.dim_blocks_total = plan.num_pairs * snap.num_dim_blocks
         stats.pairs_capacity = cap
@@ -956,13 +957,14 @@ class SelfJoinEngine:
         actually run.
         """
         cfg, eng = self.config, self.engine
-        tiles, tile_len, _, _, plan, backend, _ = self._self_tables(
-            dec, self.snapshot
-        )
-        est = batching_mod.estimate_result_size(
-            tiles, tile_len, plan, eps=eps, backend=backend,
-            sample_frac=cfg.sample_frac,
-        )
+        with obs.span("engine.pairs.estimate", "plan", eps=eps):
+            tiles, tile_len, _, _, plan, backend, _ = self._self_tables(
+                dec, self.snapshot
+            )
+            est = batching_mod.estimate_result_size(
+                tiles, tile_len, plan, eps=eps, backend=backend,
+                sample_frac=cfg.sample_frac,
+            )
         return batching_mod.suggest_pairs_capacity(est, eng.pairs_headroom)
 
     def query(

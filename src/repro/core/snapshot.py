@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.grid import (
     GridIndex,
     TilePlan,
@@ -58,10 +59,11 @@ def _chunk_list(
     """Padded device chunks of a candidate pair list, cached per chunk size."""
     got = cache.get(chunk)
     if got is None:
-        got = [
-            (pa, pb, real)
-            for _, pa, pb, real in ops._chunks(pair_a, pair_b, chunk)
-        ]
+        with obs.span("engine.chunk_tables", "transfer", chunk=chunk):
+            got = [
+                (pa, pb, real)
+                for _, pa, pb, real in ops._chunks(pair_a, pair_b, chunk)
+            ]
         cache[chunk] = got
     return got
 
@@ -156,7 +158,14 @@ class GridSnapshot:
         )
         self._dense: Optional[DenseTables] = None
         self._chunk_cache: dict = {}
-        if grid is not None:
+        if grid is None:
+            self.tiles = None
+            self.tile_len = None
+            self.tile_start = None
+            self.point_order = None
+            self.point_order_padded = None
+            return
+        with obs.span("engine.plan.place", "plan", tiles=n_tiles):
             self.tile_start = jnp.asarray(
                 pad_axis0(plan.tile_start, self.tile_rows), jnp.int32
             )
@@ -180,12 +189,6 @@ class GridSnapshot:
                 tile_size=config.tile_size,
                 dim_block=config.dim_block,
             )
-        else:
-            self.tiles = None
-            self.tile_len = None
-            self.tile_start = None
-            self.point_order = None
-            self.point_order_padded = None
 
     # -- constructors ------------------------------------------------------
 
@@ -210,18 +213,24 @@ class GridSnapshot:
         """
         pts = np.ascontiguousarray(np.asarray(d, dtype=np.float32))
         eps = config.eps if eps is None else float(eps)
-        if isinstance(perm, str) and perm == _AUTO_PERM:
+        auto = isinstance(perm, str) and perm == _AUTO_PERM
+        if auto:
             perm = None
-            if config.reorder and pts.shape[0]:
-                _, perm = variance_reorder(pts, config.sample_frac)
         elif perm is not None:
             perm = np.asarray(perm)
-        work = pts if perm is None else apply_reorder(pts, perm)
+        work = pts
+        if perm is not None or (auto and config.reorder and pts.shape[0]):
+            with obs.span("engine.plan.reorder", "plan"):
+                if perm is None:
+                    _, perm = variance_reorder(pts, config.sample_frac)
+                work = apply_reorder(pts, perm)
         grid = plan = None
         index_eps = None
         if pts.shape[0]:
-            grid = build_grid(work, eps, config.k)  # eps=0-safe (unit bins)
-            plan = build_tile_plan(grid, config.tile_size, config.sortidu)
+            with obs.span("engine.plan.grid", "plan"):
+                grid = build_grid(work, eps, config.k)  # eps=0-safe (unit bins)
+            with obs.span("engine.plan.tiles", "plan"):
+                plan = build_tile_plan(grid, config.tile_size, config.sortidu)
             index_eps = float(eps)
         return cls(
             config, pts, perm, work, index_eps, grid, plan,

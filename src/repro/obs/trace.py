@@ -12,8 +12,9 @@ many were lost, so a runaway request stream can never exhaust host memory.
 
 ``to_chrome_trace()`` exports the buffer in Chrome-trace / Perfetto JSON
 (``chrome://tracing``, https://ui.perfetto.dev).  ``enable(jax_bridge=True)``
-additionally opens a ``jax.profiler.TraceAnnotation`` around every span so
-obs spans line up with XLA device traces.
+additionally opens a ``jax.profiler.TraceAnnotation`` around every span, and
+writes every instant event as a zero-length annotation of the same name, so
+obs spans and instants line up with XLA device traces.
 
 This module deliberately imports nothing from ``repro.core`` at module
 scope: the engine imports ``repro.obs``, and an eager core import here
@@ -96,7 +97,8 @@ def enable(capacity: int = DEFAULT_CAPACITY, *, jax_bridge: bool = False) -> Non
     """Start recording into a fresh ring buffer of ``capacity`` events.
 
     ``jax_bridge=True`` wraps every span in a ``jax.profiler``
-    ``TraceAnnotation`` so obs spans appear in XLA profiler timelines too.
+    ``TraceAnnotation`` (and writes every instant as an empty one) so obs
+    spans and instants appear in XLA profiler timelines too.
     """
     if capacity < 1:
         raise ValueError(f"capacity must be >= 1, got {capacity}")
@@ -246,6 +248,10 @@ def event(name: str, cat: str = "event", **attrs) -> None:
     """Record an instant event (zero duration).  No-op if disabled."""
     if not _state.enabled:
         return
+    bridge = _state.bridge
+    if bridge is not None:
+        with bridge(name):
+            pass
     _record(
         SpanEvent(
             name,

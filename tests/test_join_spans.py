@@ -1,0 +1,142 @@
+"""Host spans of a whole join's phases (DESIGN.md #11).
+
+A join's host time is split into named leaf spans, each recorded exactly
+once where its work runs:
+
+  * ``engine.plan.reorder`` / ``.grid`` / ``.tiles`` / ``.place`` nest in
+    ``engine.snapshot_build`` (or ``engine.snapshot_rebuild``);
+  * ``engine.chunk_tables`` (the padded chunk uploads) nests in
+    ``engine.count`` / ``engine.pairs`` on a chunk-cache miss only;
+  * ``engine.pairs.estimate`` (the result-size sample) precedes
+    ``engine.pairs`` in auto-sized mode, ``engine.pairs.fetch`` (the copy
+    back) follows its last attempt.
+
+With tracing off none of them records anything.
+"""
+import numpy as np
+import pytest
+
+from oracles import brute_counts, make_dataset
+from repro import obs
+from repro.core import GridSnapshot, SelfJoinConfig, SelfJoinEngine, self_join
+
+PLAN = ("engine.plan.reorder", "engine.plan.grid", "engine.plan.tiles",
+        "engine.plan.place")
+JOIN = {"count": "engine.count", "pairs": "engine.pairs"}
+
+
+def _data():
+    return make_dataset("clustered", 301, 8, seed=7)
+
+
+def _cfg(**kw):
+    return SelfJoinConfig(eps=0.25, k=4, tile_size=16, **kw)
+
+
+def _inside(inner, outer):
+    return (
+        outer.ts_us <= inner.ts_us
+        and inner.ts_us + inner.dur_us <= outer.ts_us + outer.dur_us
+        and inner.depth == outer.depth + 1
+    )
+
+
+def _one(cap, name):
+    (ev,) = cap.spans(name)
+    return ev
+
+
+@pytest.mark.parametrize("mode", ["count", "pairs"])
+def test_each_phase_once_per_join_under_its_parent(mode):
+    d = _data()
+    with obs.capture() as cap:
+        res = self_join(d, _cfg(), return_pairs=mode == "pairs")
+    np.testing.assert_array_equal(res.counts, brute_counts(d, 0.25))
+    build = _one(cap, "engine.snapshot_build")
+    for name in PLAN:
+        assert _inside(_one(cap, name), build), name
+    join = _one(cap, JOIN[mode])
+    assert _inside(_one(cap, "engine.chunk_tables"), join)
+    if mode == "pairs":
+        est, fetch = _one(cap, "engine.pairs.estimate"), _one(cap, "engine.pairs.fetch")
+        assert est.depth == fetch.depth == join.depth == 0
+        assert build.ts_us + build.dur_us <= est.ts_us
+        assert est.ts_us + est.dur_us <= join.ts_us
+        assert join.ts_us + join.dur_us <= fetch.ts_us
+    else:
+        assert cap.span_count("engine.pairs.estimate") == 0
+        assert cap.span_count("engine.pairs.fetch") == 0
+
+
+@pytest.mark.parametrize("mode", ["count", "pairs"])
+def test_chunk_tables_only_on_a_cache_miss(mode):
+    eng = SelfJoinEngine(_data(), _cfg())
+    run = eng.count if mode == "count" else eng.pairs
+    with obs.capture() as first:
+        run()
+    with obs.capture() as second:
+        run()
+    assert first.span_count("engine.chunk_tables") == 1
+    assert second.span_count("engine.chunk_tables") == 0
+    assert second.span_count(JOIN[mode]) == 1
+
+
+def test_no_estimate_with_an_explicit_capacity():
+    with obs.capture() as cap:
+        res = self_join(_data(), _cfg(), return_pairs=True, max_pairs=1 << 16)
+    assert res.stats.overflow_retries == 0
+    assert cap.span_count("engine.pairs.estimate") == 0
+    assert cap.span_count("engine.pairs.fetch") == 1
+
+
+def test_fetch_once_across_overflow_retries():
+    eng = SelfJoinEngine(_data(), _cfg())
+    with obs.capture() as cap:
+        res = eng.pairs(_cap_hint=1)  # undersized buffer: grow and retry
+    assert res.stats.overflow_retries >= 1
+    assert cap.span_count("engine.pairs") == res.stats.overflow_retries + 1
+    assert cap.span_count("engine.pairs.retry") == res.stats.overflow_retries
+    assert cap.span_count("engine.pairs.fetch") == 1
+    # the retry reuses the chunk tables the first attempt uploaded
+    assert cap.span_count("engine.chunk_tables") == 1
+    last = cap.spans("engine.pairs")[-1]
+    assert last.ts_us + last.dur_us <= _one(cap, "engine.pairs.fetch").ts_us
+
+
+def test_query_sweep_estimates_once():
+    eng = SelfJoinEngine(_data(), _cfg())
+    with obs.capture() as cap:
+        out = eng.query([0.1, 0.2, 0.25], return_pairs=True)
+    assert cap.span_count("engine.pairs.estimate") == 1
+    assert cap.span_count("engine.pairs.fetch") == len(out) == 3
+
+
+def test_rebuild_and_persistence_paths():
+    d = _data()
+    eng = SelfJoinEngine(d, _cfg())
+    with obs.capture() as cap:
+        eng.count(eps=0.3)  # outgrows the index: transparent rebuild
+    rebuild = _one(cap, "engine.snapshot_rebuild")
+    # the rebuild reuses the permutation: applying it is the reorder phase
+    for name in PLAN:
+        assert _inside(_one(cap, name), rebuild), name
+    snap = eng.snapshot
+    with obs.capture() as cap:
+        GridSnapshot.from_arrays(
+            snap.pts, snap.perm, snap.grid, snap.plan, snap.index_eps, snap.config
+        )
+    assert [e.name for e in cap.events] == ["engine.plan.place"]
+
+
+def test_no_reorder_span_without_reorder():
+    with obs.capture() as cap:
+        self_join(_data(), _cfg(reorder=False))
+    assert cap.span_count("engine.plan.reorder") == 0
+    assert all(cap.span_count(n) == 1 for n in PLAN[1:])
+
+
+@pytest.mark.parametrize("mode", ["count", "pairs"])
+def test_disabled_tracer_records_no_phase(mode):
+    assert not obs.enabled()
+    self_join(_data(), _cfg(), return_pairs=mode == "pairs")
+    assert obs.event_count() == 0
