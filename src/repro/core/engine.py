@@ -13,7 +13,7 @@ is jitted device code:
   count scatter-- per-point neighbour counts accumulate via an in-jit
                   scatter-add over the grid-sorted layout (the host
                   ``np.add.at`` is gone);
-  pairs        -- device-side stream compaction (prefix-sum over the hit
+  pairs        -- device-side stream compaction (rank-select over the hit
                   mask) into a preallocated ``max_pairs`` buffer with an
                   overflow flag (the host ``np.nonzero`` is gone), already
                   mapped to original point ids via a device gather.
@@ -166,12 +166,11 @@ def pairs_chunk_step(
     host-side (concrete) inputs.
 
     Compaction is rank-select, not scatter (scatter over the full C*T*T
-    mask serializes badly on CPU XLA): a row-wise prefix sum over the hit
-    mask (C independent chains, then a tiny base scan) gives every hit its
-    global output rank; ``searchsorted`` recovers the flat positions of
-    ranks 1..hit_cap, and the gathered (a, b) rows land in ``buf`` as one
+    mask serializes badly on CPU XLA): ``_select_hits`` finds the
+    (pair, row, lane) position of each output rank 0..hit_cap-1 in flat
+    mask order, and the gathered (a, b) rows land in ``buf`` as one
     contiguous ``dynamic_update_slice`` block at ``offset``.  Ranks past
-    the chunk's true hit count select clamped garbage that the next
+    the chunk's true hit count select in-range garbage that the next
     chunk's block (or the final slice) overwrites.  Nothing is lost
     silently: ``offset`` advances by the exact hit count, and the host
     driver retries with a larger buffer when ``offset`` exceeds the
@@ -184,24 +183,12 @@ def pairs_chunk_step(
         tiles, tile_len, pa, pb, eps,
         shortc=True, backend=backend, return_mask=True,
     )
-    t = tiles.shape[2]
     c = pa.shape[0]
     cap = buf.shape[0] - hit_cap
 
     pair_valid = jnp.arange(c, dtype=jnp.int32) < real
-    hits = (mask.astype(jnp.bool_) & pair_valid[:, None, None]).reshape(
-        c, t * t
-    ).astype(jnp.int32)
-    row_cum = jnp.cumsum(hits, axis=1)            # C independent prefix sums
-    row_tot = row_cum[:, -1]
-    base = jnp.cumsum(row_tot) - row_tot          # (C,) exclusive
-    cum = (row_cum + base[:, None]).reshape(-1)   # global inclusive ranks
-    nh = row_tot.sum(dtype=jnp.int32)
-    ranks = jnp.arange(1, hit_cap + 1, dtype=jnp.int32)
-    hit_idx = jnp.minimum(jnp.searchsorted(cum, ranks), c * t * t - 1)
-    p_ = hit_idx // (t * t)
-    i_ = (hit_idx // t) % t
-    j_ = hit_idx % t
+    hit = mask.astype(jnp.bool_) & pair_valid[:, None, None]
+    p_, i_, j_, nh = _select_hits(hit, hit_cap)
     a_orig = point_order[tile_start[pa[p_]] + i_]
     b_orig = point_order[tile_start[pb[p_]] + j_]
     block = jnp.stack([a_orig, b_orig], axis=1)           # (hit_cap, 2)
@@ -211,6 +198,39 @@ def pairs_chunk_step(
     offset = offset + nh
     max_chunk_hits = jnp.maximum(max_chunk_hits, nh)
     return buf, offset, max_chunk_hits
+
+
+def _select_hits(hit, hit_cap):
+    """Positions (p, i, j) of hit ranks 0..hit_cap-1, and the hit count.
+
+    ``hit`` is the chunk's (C, T, T) bool mask; ranks follow its flat
+    (pair, row, lane) order.  Each rank is resolved level by level: the
+    tile pair from the C inclusive pair totals, the row from that pair's
+    T inclusive row totals (one row gather), the lane from that row's T
+    hits (one row gather).  No prefix sum over all C*T*T flags and no
+    loop.
+    """
+    c, t, _ = hit.shape
+    row_incl = jnp.cumsum(hit.sum(-1, dtype=jnp.int32), axis=1)  # (C, T)
+    pair_incl = jnp.cumsum(row_incl[:, -1])                      # (C,)
+    rank = jnp.arange(hit_cap, dtype=jnp.int32)
+    p_, rank = _select_level(pair_incl[None, :], rank)
+    p_ = jnp.minimum(p_, c - 1)
+    i_, rank = _select_level(row_incl[p_], rank)
+    i_ = jnp.minimum(i_, t - 1)
+    lanes = hit.reshape(c * t, t)[p_ * t + i_].astype(jnp.int32)
+    j_, _ = _select_level(jnp.cumsum(lanes, axis=1), rank)
+    return p_, i_, jnp.minimum(j_, t - 1), pair_incl[-1]
+
+
+def _select_level(incl, rank):
+    """Per rank: the number of inclusive counts at or below it (the index
+    of the entry holding it) and its rank within that entry."""
+    below = incl <= rank[:, None]
+    return (
+        below.sum(1, dtype=jnp.int32),
+        rank - jnp.max(jnp.where(below, incl, 0), axis=1),
+    )
 
 
 @functools.wraps(pairs_chunk_step)
