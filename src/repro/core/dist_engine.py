@@ -83,6 +83,7 @@ from repro.core.engine import (
     _count_chunk_program,
     _pairs_chunk_program,
     count_chunk_step,
+    hit_window,
     pairs_chunk_step,
 )
 from repro.core.grid import adjacent_cell_pairs, build_grid, pad_axis0
@@ -846,7 +847,7 @@ class DistributedSelfJoinEngine:
 
         t = cfg.tile_size
         flat_per_chunk = eng.pairs_chunk * t * t
-        hit_cap = min(flat_per_chunk, 4096)
+        hit_cap = hit_window(0, flat_per_chunk)
         cap = 1 << (max(total, 1) - 1).bit_length()  # pow2: bounded trace keys
         for _ in range(_MAX_AUTO_GROW + 1):
             buf = jnp.zeros((cap + hit_cap, 2), jnp.int32)
@@ -866,9 +867,7 @@ class DistributedSelfJoinEngine:
                 stats.num_chunks += 1
             if int(max_hits) <= hit_cap:
                 break
-            hit_cap = min(
-                flat_per_chunk, 1 << (int(max_hits) - 1).bit_length()
-            )
+            hit_cap = hit_window(int(max_hits), flat_per_chunk)
         num = int(offset)
         if num != total:
             raise RuntimeError(

@@ -76,6 +76,23 @@ from repro import obs
 from repro.kernels import ops
 
 _MAX_AUTO_GROW = 8  # doublings before giving up on an auto-sized buffer
+_MIN_HIT_WINDOW = 4096  # narrowest rank window a pairs chunk program runs
+# Auto-mode first rank window: the rung at or above this many times the
+# size estimate's mean hits per chunk.  Syn64D2M at eps 0.18 (a v5e, four
+# seeds) has a mean of 4.9-5.0k hits a chunk, p90 7.7-7.9k, max 14.7-18.0k:
+# a factor of 1 gives 8,192 ranks and leaves ~8 % of the chunks to the
+# remainder pass, which is cheaper than widening every chunk's select --
+# a pairs join took 5.0-5.3 s at 1, 5.7-6.0 s at 2 (16,384), 7.8-7.9 s at
+# 4.  Syn16D2M (~67 hits a chunk) stays at the 4,096 floor.
+_WINDOW_PER_MEAN = 1
+
+
+def hit_window(n: int, flat_per_chunk: int) -> int:
+    """The rank window for ``n`` hits: the power of two at or above ``n``,
+    at least 4,096 and at most ``flat_per_chunk`` (C*T*T, a chunk's every
+    flag).  Powers of two keep the compiled pairs programs few."""
+    pow2 = 1 << max(int(n) - 1, 0).bit_length()
+    return min(flat_per_chunk, max(_MIN_HIT_WINDOW, pow2))
 
 
 # ---------------------------------------------------------------------------
@@ -148,7 +165,7 @@ def pairs_chunk_step(
     point_order,    # (N,) int32 grid-sorted -> original id
     pa, pb, real, eps,
     *,
-    hit_cap, backend,
+    hit_cap, backend, first_rank=0,
 ):
     """One pairs-mode chunk: evaluate + compact into ``buf``, fully on device.
 
@@ -167,17 +184,23 @@ def pairs_chunk_step(
 
     Compaction is rank-select, not scatter (scatter over the full C*T*T
     mask serializes badly on CPU XLA): ``_select_hits`` finds the
-    (pair, row, lane) position of each output rank 0..hit_cap-1 in flat
-    mask order, and the gathered (a, b) rows land in ``buf`` as one
-    contiguous ``dynamic_update_slice`` block at ``offset``.  Ranks past
-    the chunk's true hit count select in-range garbage that the next
-    chunk's block (or the final slice) overwrites.  Nothing is lost
-    silently: ``offset`` advances by the exact hit count, and the host
-    driver retries with a larger buffer when ``offset`` exceeds the
-    capacity, or with a larger ``hit_cap`` when ``max_chunk_hits`` says a
-    single chunk outgrew the rank window.  Per-point counts are *not*
-    accumulated here -- they fall out of the finished buffer in one
-    scatter (``_counts_from_pairs``).
+    (pair, row, lane) position of each output rank
+    first_rank..first_rank+hit_cap-1 in flat mask order, and the gathered
+    (a, b) rows land in ``buf`` as one contiguous ``dynamic_update_slice``
+    block at ``offset``.  With ``first_rank`` 0, ranks past the chunk's
+    true hit count select in-range garbage that the next chunk's block (or
+    the final slice) overwrites.  Nothing is lost silently: ``offset``
+    advances by the exact hit count, the host driver retries with a larger
+    buffer when ``offset`` exceeds the capacity, and when
+    ``max_chunk_hits`` says a chunk outgrew the rank window, the engine's
+    remainder pass (``SelfJoinEngine.pairs``) runs this body again for
+    that chunk alone with ``first_rank`` = the first pass's window and
+    ``offset`` = the start of the chunk's gap (the rings and the serving
+    tier, which call it with ``first_rank`` 0, widen the window and run
+    again).  A remainder block is followed by the next chunk's pairs, so
+    its rows past the chunk's hits keep what ``buf`` holds.  Per-point
+    counts are *not* accumulated here -- they fall out of the finished
+    buffer in one scatter (``_counts_from_pairs``).
     """
     _, _, mask = ops.eval_tile_pairs(
         tiles, tile_len, pa, pb, eps,
@@ -188,11 +211,15 @@ def pairs_chunk_step(
 
     pair_valid = jnp.arange(c, dtype=jnp.int32) < real
     hit = mask.astype(jnp.bool_) & pair_valid[:, None, None]
-    p_, i_, j_, nh = _select_hits(hit, hit_cap)
+    p_, i_, j_, nh = _select_hits(hit, hit_cap, first_rank)
     a_orig = point_order[tile_start[pa[p_]] + i_]
     b_orig = point_order[tile_start[pb[p_]] + j_]
     block = jnp.stack([a_orig, b_orig], axis=1)           # (hit_cap, 2)
     woff = jnp.minimum(offset, cap)  # post-overflow blocks land in padding
+    if first_rank:
+        live = first_rank + jnp.arange(hit_cap, dtype=jnp.int32) < nh
+        kept = jax.lax.dynamic_slice(buf, (woff, jnp.int32(0)), block.shape)
+        block = jnp.where(live[:, None], block, kept)
     buf = jax.lax.dynamic_update_slice(buf, block, (woff, jnp.int32(0)))
 
     offset = offset + nh
@@ -200,8 +227,9 @@ def pairs_chunk_step(
     return buf, offset, max_chunk_hits
 
 
-def _select_hits(hit, hit_cap):
-    """Positions (p, i, j) of hit ranks 0..hit_cap-1, and the hit count.
+def _select_hits(hit, hit_cap, first_rank=0):
+    """Positions (p, i, j) of hit ranks first_rank..first_rank+hit_cap-1,
+    and the hit count.
 
     ``hit`` is the chunk's (C, T, T) bool mask; ranks follow its flat
     (pair, row, lane) order.  Each rank is resolved level by level: the
@@ -213,7 +241,7 @@ def _select_hits(hit, hit_cap):
     c, t, _ = hit.shape
     row_incl = jnp.cumsum(hit.sum(-1, dtype=jnp.int32), axis=1)  # (C, T)
     pair_incl = jnp.cumsum(row_incl[:, -1])                      # (C,)
-    rank = jnp.arange(hit_cap, dtype=jnp.int32)
+    rank = jnp.arange(hit_cap, dtype=jnp.int32) + first_rank
     p_, rank = _select_level(pair_incl[None, :], rank)
     p_ = jnp.minimum(p_, c - 1)
     i_, rank = _select_level(row_incl[p_], rank)
@@ -240,7 +268,7 @@ def _pairs_chunk_traced(*args, **kwargs):
 
 
 _pairs_chunk_program = functools.partial(
-    jax.jit, static_argnames=("hit_cap", "backend")
+    jax.jit, static_argnames=("hit_cap", "backend", "first_rank")
 )(_pairs_chunk_traced)
 
 
@@ -864,6 +892,7 @@ class SelfJoinEngine:
         eps: Optional[float] = None,
         max_pairs: Optional[int] = None,
         _cap_hint: Optional[int] = None,
+        _est_hint: Optional[int] = None,
     ) -> SelfJoinResult:
         """Counts plus the materialized (a, b) pair list, original ids.
 
@@ -871,8 +900,11 @@ class SelfJoinEngine:
         overflow raises ``RuntimeError``.  Otherwise the buffer is sized
         from the paper's result-size estimate (Sec. 3.2.2); on overflow
         the exact |R| is known after the pass, so the buffer regrows to
-        it in a single retry.  ``_cap_hint`` lets ``query()`` supply one
-        shared auto-mode capacity for a whole eps sweep.
+        it in a single retry.  The estimate also sets the first pass's
+        rank window (``_WINDOW_PER_MEAN``); each chunk that outgrew it
+        goes again alone for the ranks it is missing (``_remainders``).
+        ``_est_hint`` lets ``query()`` supply one shared estimate for a
+        whole eps sweep; ``_cap_hint`` sets the capacity with no estimate.
         """
         eps = self.config.eps if eps is None else float(eps)
         if self.num_points == 0:
@@ -891,31 +923,38 @@ class SelfJoinEngine:
 
         explicit = max_pairs if max_pairs is not None else eng.max_pairs
         auto = explicit is None
+        t = cfg.tile_size
+        flat_per_chunk = eng.pairs_chunk * t * t
+        window = hit_window(0, flat_per_chunk)
         if not auto:
             cap = int(explicit)
         elif _cap_hint is not None:
             cap = int(_cap_hint)
         else:
-            cap = self._auto_capacity(eps, dec)
-        t = cfg.tile_size
-        flat_per_chunk = eng.pairs_chunk * t * t
-        hit_cap = min(flat_per_chunk, 4096)
+            est = self._estimate(eps, dec) if _est_hint is None else _est_hint
+            cap = batching_mod.suggest_pairs_capacity(est, eng.pairs_headroom)
+            num_chunks = max(1, -(-plan.num_pairs // eng.pairs_chunk))
+            window = hit_window(
+                -(-_WINDOW_PER_MEAN * est // num_chunks), flat_per_chunk
+            )
 
         retries = 0
         dispatches = 0
+        rank_rows = 0
         while True:
             stats = self._base_stats(eps, snap)
             self._record_decision(stats, dec)
             if dec.execution == "dense":
                 stats.num_tile_pairs_evaluated = plan.num_pairs
                 stats.num_candidates = plan.num_candidates
-            buf = jnp.zeros((cap + hit_cap, 2), jnp.int32)
+            buf = jnp.zeros((cap + window, 2), jnp.int32)
             offset = jnp.zeros((), jnp.int32)
             max_hits = jnp.zeros((), jnp.int32)
+            ends = []  # each chunk's end offset, left on the device
             with obs.span(
                 "engine.pairs", "join",
                 n=snap.num_points, eps=eps, tier=dec.execution,
-                attempt=retries,
+                attempt=retries, window=window,
             ):
                 for pa, pb, real in chunks(eng.pairs_chunk):
                     with obs.span("engine.pairs.chunk", "dispatch"):
@@ -923,22 +962,15 @@ class SelfJoinEngine:
                             buf, offset, max_hits,
                             tiles, tile_len, tile_start,
                             snap.point_order, pa, pb, real, eps,
-                            hit_cap=hit_cap, backend=backend,
+                            hit_cap=window, backend=backend,
                         )
+                    ends.append(offset)
                     stats.num_chunks += 1
                     dispatches += 1
+                rank_rows += stats.num_chunks * window
                 num = int(offset)
-            # exact totals are known after a full pass, so each overflow kind
-            # resolves in one retry: widen the per-chunk rank window first,
-            # then (auto mode) regrow the buffer to the true |R|.
-            if int(max_hits) > hit_cap and retries < _MAX_AUTO_GROW:
-                obs.event(
-                    "engine.pairs.retry", "retry", kind="hit_cap",
-                    max_hits=int(max_hits), hit_cap=hit_cap,
-                )
-                hit_cap = min(flat_per_chunk, -(-int(max_hits) // 1024) * 1024)
-                retries += 1
-                continue
+            # exact |R| is known after a full pass: (auto mode) regrow the
+            # buffer to it and run the join again
             if num > cap:
                 if auto and eng.auto_grow and retries < _MAX_AUTO_GROW:
                     obs.event(
@@ -954,6 +986,16 @@ class SelfJoinEngine:
                 )
             break
 
+        if int(max_hits) > window:
+            buf, extra, rows = self._remainders(
+                buf, ends, window, flat_per_chunk, int(max_hits),
+                chunks(eng.pairs_chunk), tiles, tile_len, tile_start,
+                snap.point_order, eps, backend,
+            )
+            retries += 1
+            dispatches += extra
+            rank_rows += rows
+
         with obs.span("engine.pairs.fetch", "transfer", num=num):
             pairs = np.asarray(buf[:num])
             counts = np.asarray(
@@ -966,26 +1008,74 @@ class SelfJoinEngine:
         stats.pairs_capacity = cap
         stats.overflow_retries = retries
         stats.num_device_dispatches = dispatches
+        stats.rank_rows = rank_rows
         obs.mirror_selfjoin_stats(stats, path="engine", mode="pairs")
         return SelfJoinResult(counts=counts, stats=stats, pairs=pairs)
 
-    def _auto_capacity(self, eps: float, dec: cost_mod.TierDecision) -> int:
-        """Auto-mode pairs-buffer capacity from the paper's |R| estimate.
+    @staticmethod
+    def _remainders(
+        buf, ends, window, flat_per_chunk, max_hits, chunk_list,
+        tiles, tile_len, tile_start, point_order, eps, backend,
+    ):
+        """Ranks ``window..`` of every chunk that outgrew the first pass's
+        rank window, written into the gap the pass left for them.
+
+        The pass advanced ``offset`` by each chunk's exact hit count, so a
+        chunk with ``nh > window`` hits left rows ``[start + window, start +
+        nh)`` unwritten.  Each such chunk runs the same chunk program again,
+        grouped by the window rung at or above ``nh - window`` (one compile
+        per rung), from first rank ``window``.  The buffer gains the widest
+        rung as padding so that no block is clamped.  Returns the buffer,
+        the dispatches made and their rank-window rows.
+        """
+        obs.event(
+            "engine.pairs.retry", "retry", kind="remainder",
+            max_hits=max_hits, hit_cap=window,
+        )
+        rows = 0
+        with obs.span("engine.pairs.remainder", "join") as span:
+            end = np.asarray(jax.device_get(ends), np.int64)  # one read back
+            nh = np.diff(end, prepend=0)
+            over = np.flatnonzero(nh > window)
+            rungs: Dict[int, List[int]] = {}
+            for i in over:
+                rungs.setdefault(
+                    hit_window(nh[i] - window, flat_per_chunk), []
+                ).append(int(i))
+            span.set(chunks=int(over.size), rungs=len(rungs))
+            buf = jnp.pad(buf, ((0, max(rungs)), (0, 0)))
+            zero = jnp.zeros((), jnp.int32)
+            for rung, idx in sorted(rungs.items()):
+                for i in idx:
+                    pa, pb, real = chunk_list[i]
+                    obs.event("engine.pairs.remainder.chunk", "dispatch", rung=rung)
+                    buf, _, _ = _pairs_chunk_program(
+                        buf, jnp.int32(end[i] - nh[i] + window), zero,
+                        tiles, tile_len, tile_start, point_order,
+                        pa, pb, real, eps,
+                        hit_cap=rung, backend=backend, first_rank=window,
+                    )
+                    rows += rung
+            buf.block_until_ready()  # the span holds the pass's device time
+        return buf, int(over.size), rows
+
+    def _estimate(self, eps: float, dec: cost_mod.TierDecision) -> int:
+        """The paper's |R| estimate, which sizes the pairs buffer and the
+        first rank window in auto mode.
 
         The estimate samples the *chosen* tier's candidate pair list with
-        the chosen backend, so the capacity reflects the tables that will
-        actually run.
+        the chosen backend, so it reflects the tables that will actually
+        run.
         """
-        cfg, eng = self.config, self.engine
+        cfg = self.config
         with obs.span("engine.pairs.estimate", "plan", eps=eps):
             tiles, tile_len, _, _, plan, backend, _ = self._self_tables(
                 dec, self.snapshot
             )
-            est = batching_mod.estimate_result_size(
+            return batching_mod.estimate_result_size(
                 tiles, tile_len, plan, eps=eps, backend=backend,
                 sample_frac=cfg.sample_frac,
             )
-        return batching_mod.suggest_pairs_capacity(est, eng.pairs_headroom)
 
     def query(
         self,
@@ -999,19 +1089,20 @@ class SelfJoinEngine:
         runs through the already-compiled chunk programs (eps is traced, so
         no recompilation happens between sweep points).  In auto-sized pairs
         mode the result-size estimate also runs once, at the largest eps --
-        its capacity bounds every smaller sweep point.
+        the capacity and first rank window it sets bound every smaller
+        sweep point.
         """
         eps_list = [float(e) for e in eps_values]
         if eps_list and self.num_points:
             self._ensure_index(max(eps_list))
         if return_pairs:
-            cap_hint = None
+            est = None
             explicit = max_pairs if max_pairs is not None else self.engine.max_pairs
             if explicit is None and eps_list and self.num_points:
                 dec = self.resolve_execution(max(eps_list))
-                cap_hint = self._auto_capacity(max(eps_list), dec)
+                est = self._estimate(max(eps_list), dec)
             return [
-                self.pairs(e, max_pairs=max_pairs, _cap_hint=cap_hint)
+                self.pairs(e, max_pairs=max_pairs, _est_hint=est)
                 for e in eps_list
             ]
         return [self.count(e) for e in eps_list]

@@ -89,6 +89,8 @@ class SelfJoinStats:
     num_chunks: int = 0                  # device programs dispatched (engine)
     pairs_capacity: int = 0              # preallocated pairs buffer rows (engine)
     overflow_retries: int = 0            # auto-grow retries in pairs mode (engine)
+    rank_rows: int = 0                   # rank-window rows over every pairs
+                                         # dispatch of the join (engine)
     num_workers: int = 0                 # |p| (distributed engine)
     num_rounds: int = 0                  # ring rounds executed (= |p|)
     worker_pair_cursors: tuple = ()      # per-worker final pairs-buffer cursor

@@ -66,6 +66,7 @@ import numpy as np
 from repro.core.engine import (
     QueryPlanTables,
     count_chunk_step,
+    hit_window,
     pairs_chunk_step,
 )
 from repro.core.grid import pad_axis0
@@ -302,7 +303,7 @@ class QueryService:
         t = int(self.index.config.tile_size)
         backend = self._tier_kwargs(tab)["backend"]
         flat_per_chunk = self._pairs_chunk * t * t
-        hit_cap = min(flat_per_chunk, 4096)
+        hit_cap = hit_window(0, flat_per_chunk)
         cap = 1 << (max(int(total), 1) - 1).bit_length()  # pow2: bounded trace keys
         for _ in range(_MAX_HITCAP_RETRIES + 1):
             buf = jnp.zeros((cap + hit_cap, 2), jnp.int32)
@@ -327,9 +328,7 @@ class QueryService:
                 "service.pairs.retry", "retry", kind="hit_cap",
                 max_hits=int(max_hits), hit_cap=hit_cap,
             )
-            hit_cap = min(
-                flat_per_chunk, 1 << (int(max_hits) - 1).bit_length()
-            )
+            hit_cap = hit_window(int(max_hits), flat_per_chunk)
         num = int(offset)
         if num != total:
             raise RuntimeError(

@@ -126,6 +126,9 @@ def mirror_selfjoin_stats(stats, *, path: str, mode: str) -> None:
     c("selfjoin_overflow_retries_total", "pairs-buffer regrow retries").inc(
         stats.overflow_retries, **labels
     )
+    c("selfjoin_rank_rows_total", "pairs rank-window rows dispatched").inc(
+        stats.rank_rows, **labels
+    )
 
 
 def mirror_service_stats(stats, *, kind: str) -> None:

@@ -9,7 +9,10 @@ once where its work runs:
     ``engine.count`` / ``engine.pairs`` on a chunk-cache miss only;
   * ``engine.pairs.estimate`` (the result-size sample) precedes
     ``engine.pairs`` in auto-sized mode, ``engine.pairs.fetch`` (the copy
-    back) follows its last attempt.
+    back) follows its last attempt;
+  * ``engine.pairs.remainder`` (the chunks that outgrew the first rank
+    window, run again for their missing ranks) sits between the last
+    attempt and the fetch, once in a join that needs it.
 
 With tracing off none of them records anything.
 """
@@ -18,7 +21,9 @@ import pytest
 
 from oracles import brute_counts, make_dataset
 from repro import obs
-from repro.core import GridSnapshot, SelfJoinConfig, SelfJoinEngine, self_join
+from repro.core import (
+    EngineConfig, GridSnapshot, SelfJoinConfig, SelfJoinEngine, self_join,
+)
 
 PLAN = ("engine.plan.reorder", "engine.plan.grid", "engine.plan.tiles",
         "engine.plan.place")
@@ -140,3 +145,53 @@ def test_disabled_tracer_records_no_phase(mode):
     assert not obs.enabled()
     self_join(_data(), _cfg(), return_pairs=mode == "pairs")
     assert obs.event_count() == 0
+
+
+def _skewed_engine():
+    """Exponential data whose chunks outgrow a 4,096-rank first window."""
+    d = make_dataset("exponential", 1200, 4, seed=11)
+    return SelfJoinEngine(
+        d, SelfJoinConfig(eps=0.05, k=4, tile_size=16),
+        EngineConfig(pairs_chunk=64),
+    )
+
+
+@pytest.mark.parametrize("remainder", [True, False])
+def test_remainder_span_once_per_join_that_needs_it(remainder):
+    eng = _skewed_engine() if remainder else SelfJoinEngine(_data(), _cfg())
+    with obs.capture() as cap:
+        res = eng.pairs(max_pairs=1 << 20)
+        eng.pairs(max_pairs=1 << 20)
+    assert cap.span_count("engine.pairs.remainder") == 2 * remainder
+    assert cap.span_count("engine.pairs.retry") == 2 * remainder
+    assert res.stats.overflow_retries == remainder
+    if remainder:
+        joins, rems = cap.spans("engine.pairs"), cap.spans("engine.pairs.remainder")
+        fetches = cap.spans("engine.pairs.fetch")
+        for join, rem, fetch in zip(joins, rems, fetches):
+            assert rem.depth == 0 and rem.attrs["chunks"] >= 3
+            assert join.ts_us + join.dur_us <= rem.ts_us
+            assert rem.ts_us + rem.dur_us <= fetch.ts_us
+        # the remainder's dispatches count, each once
+        first = cap.span_count("engine.pairs.chunk")
+        again = cap.span_count("engine.pairs.remainder.chunk")
+        assert first + again == 2 * res.stats.num_device_dispatches
+        assert again == 2 * rems[0].attrs["chunks"]
+
+
+@pytest.mark.parametrize("case", ["one_pass", "remainder", "capacity_and_remainder"])
+def test_rank_rows_is_the_sum_of_dispatched_windows(case):
+    eng = SelfJoinEngine(_data(), _cfg()) if case == "one_pass" else _skewed_engine()
+    sizing = dict(_cap_hint=1) if case == "capacity_and_remainder" else {}
+    if case == "remainder":
+        sizing = dict(max_pairs=1 << 20)
+    with obs.capture() as cap:
+        res = eng.pairs(**sizing)
+    first = sum(
+        j.attrs["window"] for j in cap.spans("engine.pairs")
+    ) * res.stats.num_chunks
+    rungs = sum(e.attrs["rung"] for e in cap.spans("engine.pairs.remainder.chunk"))
+    assert res.stats.rank_rows == first + rungs
+    assert (rungs > 0) == (case != "one_pass")
+    assert cap.span_count("engine.pairs") == 1 + (case == "capacity_and_remainder")
+    assert cap.metric("selfjoin_rank_rows_total", path="engine") == res.stats.rank_rows

@@ -1,10 +1,15 @@
-"""Pairs compaction's rank-select (``engine.pairs_chunk_step``).
+"""Pairs compaction's rank-select (``engine.pairs_chunk_step``) and the
+engine's remainder pass.
 
-The chunk program writes ranks 0..hit_cap-1 of the chunk's hit mask, in
-flat (pair, row, lane) order, as one block at ``offset``, resolving each
-rank per tile pair, per row, per lane.  At every rank window it must
-write exactly what the flat ``cumsum`` + ``searchsorted`` reference below
-writes, for any mask a backend can emit.
+The chunk program writes ranks first_rank..first_rank+hit_cap-1 of the
+chunk's hit mask, in flat (pair, row, lane) order, as one block at
+``offset``, resolving each rank per tile pair, per row, per lane.  At
+every rank window it must write exactly what the flat ``cumsum`` +
+``searchsorted`` reference below writes, for any mask a backend can emit.
+A remainder block (first rank > 0) writes only the chunk's ranks and
+leaves every other row of the buffer as it was.  A join whose chunks
+outgrow the first rank window must come out identical, in order, to one
+pass at a window that holds every chunk.
 """
 import functools
 
@@ -28,9 +33,10 @@ HIT_CAPS = [1024, C * T, 3072]
 
 
 def _flat_select_reference(buf, offset, max_chunk_hits, mask, tile_start,
-                           point_order, pa, pb, real, hit_cap):
+                           point_order, pa, pb, real, hit_cap, first_rank=0):
     """The flat select: a prefix sum over all C*T*T flags, then
-    ``searchsorted`` of ranks 1..hit_cap."""
+    ``searchsorted`` of ranks first_rank+1..first_rank+hit_cap; a remainder
+    block writes only the ranks the chunk holds."""
     c, t, _ = mask.shape
     cap = buf.shape[0] - hit_cap
     valid = np.arange(c) < real
@@ -39,9 +45,8 @@ def _flat_select_reference(buf, offset, max_chunk_hits, mask, tile_start,
     row_tot = row_cum[:, -1]
     cum = (row_cum + (np.cumsum(row_tot) - row_tot)[:, None]).reshape(-1)
     nh = int(row_tot.sum())
-    idx = np.minimum(
-        np.searchsorted(cum, np.arange(1, hit_cap + 1)), c * t * t - 1
-    )
+    ranks = np.arange(first_rank + 1, first_rank + hit_cap + 1)
+    idx = np.minimum(np.searchsorted(cum, ranks), c * t * t - 1)
     p, i, j = idx // (t * t), idx // t % t, idx % t
     block = np.stack(
         [point_order[tile_start[pa[p]] + i], point_order[tile_start[pb[p]] + j]],
@@ -49,7 +54,11 @@ def _flat_select_reference(buf, offset, max_chunk_hits, mask, tile_start,
     )
     out = buf.copy()
     woff = min(offset, cap)
-    out[woff:woff + hit_cap] = block
+    if first_rank:
+        live = ranks <= nh
+        out[woff:woff + hit_cap][live] = block[live]
+    else:
+        out[woff:woff + hit_cap] = block
     return out, offset + nh, max(max_chunk_hits, nh)
 
 
@@ -83,14 +92,10 @@ CASES = {
 }
 
 
-@pytest.mark.parametrize("hit_cap", HIT_CAPS, ids=lambda h: f"hit_cap{h}")
-@pytest.mark.parametrize("case", list(CASES))
-def test_select_matches_flat_reference(case, hit_cap, monkeypatch):
-    layout, real, offset = CASES[case]
-    rng = np.random.default_rng([list(CASES).index(case), hit_cap])
-    mask = _mask(rng, layout, real)
-    if case == "hits_past_real":
-        assert mask[real:].any()
+def _step_and_reference(monkeypatch, rng, mask, real, offset, hit_cap,
+                        first_rank=0):
+    """One chunk step on ``mask`` and the flat reference's answer:
+    (got buf, offset, max hits), (want buf, offset, max hits)."""
     tile_start = (rng.permutation(NUM_TILES) * T).astype(np.int32)
     point_order = rng.permutation(NUM_TILES * T).astype(np.int32)
     pa = rng.integers(0, NUM_TILES, C).astype(np.int32)
@@ -106,22 +111,37 @@ def test_select_matches_flat_reference(case, hit_cap, monkeypatch):
     )
     step = jax.jit(functools.partial(
         engine_mod.pairs_chunk_step, hit_cap=hit_cap, backend="jnp",
+        first_rank=first_rank,
     ))
-    got_buf, got_off, got_max = step(
+    got = step(
         jnp.asarray(buf), jnp.int32(offset), jnp.int32(max_chunk_hits),
         jnp.asarray(mask), jnp.zeros(NUM_TILES, jnp.int32),
         jnp.asarray(tile_start), jnp.asarray(point_order),
         jnp.asarray(pa), jnp.asarray(pb), jnp.int32(real), jnp.float32(0),
     )
-    want_buf, want_off, want_max = _flat_select_reference(
+    want = _flat_select_reference(
         buf, offset, max_chunk_hits, mask, tile_start, point_order,
-        pa, pb, real, hit_cap,
+        pa, pb, real, hit_cap, first_rank,
+    )
+    return [np.asarray(g) for g in got], want
+
+
+@pytest.mark.parametrize("hit_cap", HIT_CAPS, ids=lambda h: f"hit_cap{h}")
+@pytest.mark.parametrize("case", list(CASES))
+def test_select_matches_flat_reference(case, hit_cap, monkeypatch):
+    layout, real, offset = CASES[case]
+    rng = np.random.default_rng([list(CASES).index(case), hit_cap])
+    mask = _mask(rng, layout, real)
+    if case == "hits_past_real":
+        assert mask[real:].any()
+    (got_buf, got_off, got_max), (want_buf, want_off, want_max) = (
+        _step_and_reference(monkeypatch, rng, mask, real, offset, hit_cap)
     )
     nh = want_off - offset
     written = min(offset, CAP) + min(nh, hit_cap)
     assert int(got_off) == want_off
     assert int(got_max) == want_max
-    np.testing.assert_array_equal(np.asarray(got_buf)[:written], want_buf[:written])
+    np.testing.assert_array_equal(got_buf[:written], want_buf[:written])
     if case == "nh_over_hit_cap":
         assert nh > hit_cap
     if case in ("d2e-3", "one_pair"):
@@ -129,16 +149,18 @@ def test_select_matches_flat_reference(case, hit_cap, monkeypatch):
 
 
 @pytest.mark.parametrize(
-    "n, eps, chunk, compiles",
+    "n, eps, chunk, sizing, compiles",
     [
-        (300, 0.1, 520, 1),
-        (700, 0.3, 528, 2),  # a chunk holds > 4,096 hits: one retry
+        (300, 0.1, 520, {}, 1),
+        # a chunk holds > 4,096 hits: one remainder rung
+        (700, 0.3, 528, dict(max_pairs=1 << 20), 2),
+        (700, 0.3, 528, {}, 1),
     ],
-    ids=["default_window", "widened_by_retry"],
+    ids=["default_window", "widened_by_retry", "window_from_estimate"],
 )
-def test_pairs_across_hit_cap_retry(n, eps, chunk, compiles):
-    # T = 8 and C >= 512: the retry widens the 4,096-rank window past C*T;
-    # clearing the caches makes every program of this case compile here
+def test_pairs_across_hit_cap_retry(n, eps, chunk, sizing, compiles):
+    # T = 8 and C >= 512: a window past 4,096 ranks is past C*T; clearing
+    # the caches makes every program of this case compile here
     jax.clear_caches()
     d = make_dataset("clustered", n, 4, seed=3)
     eng = SelfJoinEngine(
@@ -146,11 +168,101 @@ def test_pairs_across_hit_cap_retry(n, eps, chunk, compiles):
         EngineConfig(pairs_chunk=chunk),
     )
     with obs.capture() as cap:
-        res = eng.pairs()
+        res = eng.pairs(**sizing)
     traced = [
         e for e in cap.spans("engine.trace")
         if e.attrs.get("program") == "pairs_chunk"
     ]
     assert len(traced) == compiles
     assert res.stats.overflow_retries == compiles - 1
+    assert cap.span_count("engine.pairs.remainder") == compiles - 1
+    window = cap.spans("engine.pairs")[-1].attrs["window"]
+    if sizing or n == 300:
+        assert window == 4096  # no estimate, or one below the floor
+    else:
+        assert window > 4096
+    assert pair_set(res.pairs) == pair_set(brute_pairs(d, eps))
+
+
+# (first rank, rank window, hit density): a remainder block's ranks start
+# past the first pass's window; rows past the chunk's hits hold the next
+# chunk's pairs and must keep them
+REMAINDER_BLOCKS = {
+    "gap_inside_window": (1024, 1024, 0.047),
+    "window_ends_at_flat": (C * T * T - 4096, 4096, 0.95),
+    "no_hits_past_first_rank": (2048, 1024, 0.02),
+}
+
+
+@pytest.mark.parametrize("case", list(REMAINDER_BLOCKS))
+def test_remainder_block_leaves_other_rows(case, monkeypatch):
+    first_rank, hit_cap, density = REMAINDER_BLOCKS[case]
+    rng = np.random.default_rng([99, list(REMAINDER_BLOCKS).index(case)])
+    mask = (rng.random((C, T, T)) < density).astype(np.int8)
+    offset = 600  # the start of the chunk's gap
+    (got_buf, got_off, _), (want_buf, want_off, _) = _step_and_reference(
+        monkeypatch, rng, mask, C, offset, hit_cap, first_rank,
+    )
+    live = min(max(want_off - offset - first_rank, 0), hit_cap)
+    if case == "no_hits_past_first_rank":
+        assert live == 0
+    else:
+        assert 0 < live < hit_cap
+    assert first_rank + hit_cap <= C * T * T
+    assert int(got_off) == want_off
+    # every row of the buffer: the gap filled, nothing else touched
+    np.testing.assert_array_equal(got_buf, want_buf)
+
+
+@pytest.mark.parametrize(
+    "n, flat, want",
+    [(0, 1 << 20, 4096), (4096, 1 << 20, 4096), (4097, 1 << 20, 8192),
+     (70_000, 1 << 20, 131072), (70_000, 65536, 65536), (10, 2048, 2048)],
+)
+def test_hit_window_rungs(n, flat, want):
+    assert engine_mod.hit_window(n, flat) == want
+
+
+# skewed exponential data, tile pairs of (T=16, C=64) or (T=8, C=128): at a
+# 4,096-rank first window at least three chunks outgrow it, one by more
+# than one rung; (size, eps, chunk, tile size, sizing)
+REMAINDER_JOINS = {
+    "explicit_capacity": (1200, 0.05, 64, 16, dict(max_pairs=1 << 20)),
+    "capacity_overflow": (1200, 0.05, 64, 16, dict(_cap_hint=1)),
+    "rung_ends_at_flat": (1500, 0.05, 128, 8, dict(max_pairs=1 << 20)),
+}
+
+
+@pytest.mark.parametrize("case", list(REMAINDER_JOINS))
+def test_remainder_pass_matches_one_pass(case, monkeypatch):
+    n, eps, chunk, t, sizing = REMAINDER_JOINS[case]
+    flat = chunk * t * t
+    d = make_dataset("exponential", n, 4, seed=11)
+    eng = SelfJoinEngine(
+        d, SelfJoinConfig(eps=eps, k=4, tile_size=t),
+        EngineConfig(pairs_chunk=chunk),
+    )
+    with obs.capture() as cap:
+        res = eng.pairs(**sizing)
+    (rem,) = cap.spans("engine.pairs.remainder")
+    rungs = [e.attrs["rung"] for e in cap.spans("engine.pairs.remainder.chunk")]
+    window = cap.spans("engine.pairs")[-1].attrs["window"]
+    assert window == 4096 and rem.attrs["chunks"] == len(rungs) >= 3
+    if case == "rung_ends_at_flat":
+        assert window + max(rungs) == flat
+    else:
+        assert max(rungs) > min(rungs) == 4096 and rem.attrs["rungs"] >= 2
+    capacity = int(case == "capacity_overflow")
+    assert res.stats.overflow_retries == 1 + capacity
+
+    # one pass at a window that holds every chunk's hits
+    monkeypatch.setattr(engine_mod, "_MIN_HIT_WINDOW", flat)
+    with obs.capture() as cap:
+        one = eng.pairs(**sizing)
+    assert cap.span_count("engine.pairs.remainder") == 0
+    assert cap.spans("engine.pairs")[-1].attrs["window"] == flat
+    assert one.stats.overflow_retries == capacity
+    np.testing.assert_array_equal(res.pairs, one.pairs)
+    np.testing.assert_array_equal(res.counts, one.counts)
+    assert res.stats.num_results == one.stats.num_results == len(res.pairs)
     assert pair_set(res.pairs) == pair_set(brute_pairs(d, eps))
