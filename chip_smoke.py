@@ -201,18 +201,18 @@ def run_phases(
     del eng, snap  # free the XLA engine's device tables and chunk lists
     pl_counts = _run_twice("count_pallas_indexed", pl_eng.count).counts
     _require(np.array_equal(pl_counts, counts), "Pallas indexed != XLA counts")
-    pa, pb, real = pl_eng.snapshot.chunks(count_chunk)[0]
+    table, _ = pl_eng.snapshot.chunks(count_chunk)
     hlo = _count_chunk_program.lower(
         jax.numpy.zeros(n, jax.numpy.int32), jax.numpy.zeros((), jax.numpy.int32),
         pl_eng.snapshot.tiles, pl_eng.snapshot.tile_len,
-        pl_eng.snapshot.tile_start, pa, pb, real, np.float32(eps),
-        shortc=True, backend="pallas",
+        pl_eng.snapshot.tile_start, table.pa, table.pb, table.real, 0,
+        np.float32(eps), shortc=True, backend="pallas",
     ).compile().as_text()
     has_kernel = "tpu_custom_call" in hlo
     _log("pallas_hlo", tpu_custom_call=has_kernel)
     if on_tpu:
         _require(has_kernel, "the Pallas count program holds no TPU kernel")
-    del pl_eng, pa, pb, real
+    del pl_eng, table
 
     # -- dense tier on a subset: Pallas dense kernel vs indexed XLA -------
     sub = d[: min(dense_points, n)]

@@ -832,14 +832,16 @@ class DistributedSelfJoinEngine:
 
         counts_sorted = jnp.zeros(tab.n_slots, jnp.int32)
         skipped = jnp.zeros((), jnp.int32)
-        for pa, pb, real in tab.chunks(eng.count_chunk):
+        table, uploads = tab.chunks(eng.count_chunk)
+        stats.chunk_table_uploads += uploads
+        for i in range(table.num):
             with obs.span(
                 "ring.block.count.chunk", "dispatch", worker=k, shard=j
             ):
                 counts_sorted, skipped = _count_chunk_program(
                     counts_sorted, skipped,
                     tab.tiles, tab.tile_len, tab.tile_start,
-                    pa, pb, real, jnp.float32(eps),
+                    table.pa, table.pb, table.real, i, jnp.float32(eps),
                     shortc=shortc, backend=backend,
                 )
             stats.num_device_dispatches += 1
@@ -853,14 +855,16 @@ class DistributedSelfJoinEngine:
             buf = jnp.zeros((cap + hit_cap, 2), jnp.int32)
             offset = jnp.zeros((), jnp.int32)
             max_hits = jnp.zeros((), jnp.int32)
-            for pa, pb, real in tab.chunks(eng.pairs_chunk):
+            table, uploads = tab.chunks(eng.pairs_chunk)
+            stats.chunk_table_uploads += uploads
+            for i in range(table.num):
                 with obs.span(
                     "ring.block.pairs.chunk", "dispatch", worker=k, shard=j
                 ):
                     buf, offset, max_hits = _pairs_chunk_program(
                         buf, offset, max_hits,
                         tab.tiles, tab.tile_len, tab.tile_start, tab.order,
-                        pa, pb, real, jnp.float32(eps),
+                        table.pa, table.pb, table.real, i, jnp.float32(eps),
                         hit_cap=hit_cap, backend=backend,
                     )
                 stats.num_device_dispatches += 1
@@ -988,6 +992,7 @@ class DistributedSelfJoinEngine:
                     stats.num_candidates += s.num_candidates
                     stats.num_chunks += s.num_chunks
                     stats.num_device_dispatches += s.num_chunks
+                    stats.chunk_table_uploads += s.chunk_table_uploads
                     stats.dim_blocks_skipped += s.dim_blocks_skipped
                     stats.dim_blocks_total += s.dim_blocks_total
                     stats.num_candidates_dense += int(

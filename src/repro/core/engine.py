@@ -30,12 +30,14 @@ floor-carrying contract of ``GridSnapshot.rebuilt`` and the mutable
 index's ``compact``).
 
 Chunking / compilation-caching contract: the candidate tile-pair list is
-processed in fixed-size, zero-padded chunks; eps, the chunk's real length,
-and the running (buffer, offset, overflow, counts) state are all traced, so
-XLA compiles **at most one program per (mode, chunk shape)** and the Python
-chunk loop dispatches that same executable -- no host compute, no host
-transfers inside the loop.  The executables are module-level, shared by
-every engine instance; a multi-eps sweep recompiles nothing.
+uploaded once as a chunk table (``snapshot.ChunkTable``: fixed-size,
+zero-padded rows, a power-of-two bucket of them); eps, the chunk's row
+index and the running (buffer, offset, overflow, counts) state are all
+traced, so XLA compiles **at most one program per (mode, chunk shape,
+table bucket)** and the Python chunk loop dispatches that same executable
+-- no host compute, and no transfer but the row index and eps.  The
+executables are module-level, shared by every engine instance; a multi-eps
+sweep recompiles nothing.
 
 ``repro.core.selfjoin.self_join`` is a thin wrapper over this class.
 """
@@ -61,9 +63,10 @@ from repro.core.grid import (
 )
 from repro.core.reorder import apply_reorder
 from repro.core.snapshot import (  # noqa: F401  (re-exported compat names)
+    ChunkTable,
     DenseTables,
     GridSnapshot,
-    _chunk_list,
+    _chunk_table,
     make_dense_plan,
 )
 from repro.core.types import (
@@ -144,14 +147,31 @@ def count_chunk_step(
     return counts_sorted, skipped_tot
 
 
-@functools.wraps(count_chunk_step)
-def _count_chunk_traced(*args, **kwargs):
+def chunk_row(pa, pb, real, i):
+    """Row ``i`` of a chunk table (``snapshot.ChunkTable``), read on the
+    device: the chunk's (C,) pair indices and its real length."""
+    def row(x):
+        return jax.lax.dynamic_index_in_dim(x, i, keepdims=False)
+    return row(pa), row(pb), row(real)
+
+
+def _count_chunk_traced(
+    counts_sorted, skipped_tot, tiles, tile_len, tile_start,
+    pa, pb, real, i, eps, *, shortc, backend,
+):
+    """``count_chunk_step`` on row ``i`` of the chunk table (pa, pb, real)."""
     # Runs only while XLA traces (cache misses), so the obs event stream
     # distinguishes "compiled a new count program" from warm dispatches.
     obs.event("engine.trace", "compile", program="count_chunk")
-    return count_chunk_step(*args, **kwargs)
+    return count_chunk_step(
+        counts_sorted, skipped_tot, tiles, tile_len, tile_start,
+        *chunk_row(pa, pb, real, i), eps, shortc=shortc, backend=backend,
+    )
 
 
+# the program keeps its body's name: the device trace reads it as
+# jit_count_chunk_step
+_count_chunk_traced.__name__ = "count_chunk_step"
 _count_chunk_program = functools.partial(
     jax.jit, static_argnames=("shortc", "backend")
 )(_count_chunk_traced)
@@ -261,12 +281,20 @@ def _select_level(incl, rank):
     )
 
 
-@functools.wraps(pairs_chunk_step)
-def _pairs_chunk_traced(*args, **kwargs):
+def _pairs_chunk_traced(
+    buf, offset, max_chunk_hits, tiles, tile_len, tile_start, point_order,
+    pa, pb, real, i, eps, *, hit_cap, backend, first_rank=0,
+):
+    """``pairs_chunk_step`` on row ``i`` of the chunk table (pa, pb, real)."""
     obs.event("engine.trace", "compile", program="pairs_chunk")
-    return pairs_chunk_step(*args, **kwargs)
+    return pairs_chunk_step(
+        buf, offset, max_chunk_hits, tiles, tile_len, tile_start,
+        point_order, *chunk_row(pa, pb, real, i), eps,
+        hit_cap=hit_cap, backend=backend, first_rank=first_rank,
+    )
 
 
+_pairs_chunk_traced.__name__ = "pairs_chunk_step"
 _pairs_chunk_program = functools.partial(
     jax.jit, static_argnames=("hit_cap", "backend", "first_rank")
 )(_pairs_chunk_traced)
@@ -332,15 +360,29 @@ class QueryPlanTables:
     cost_indexed: float = 0.0      # cost model's indexed-tier estimate
     cost_dense: float = 0.0        # cost model's dense-tier estimate
     num_candidates: int = 0        # point comparisons this tier will evaluate
-    _chunk_cache: Dict[int, list] = dataclasses.field(default_factory=dict)
+    _chunk_cache: Dict[int, ChunkTable] = dataclasses.field(default_factory=dict)
 
     @property
     def num_pairs(self) -> int:
         return int(self.pair_a.shape[0])
 
-    def chunks(self, chunk: int) -> List[Tuple[jax.Array, jax.Array, int]]:
-        """Padded device chunks of the candidate pair list, cached per size."""
-        return _chunk_list(self.pair_a, self.pair_b, chunk, self._chunk_cache)
+    def chunks(self, chunk: int) -> Tuple[ChunkTable, int]:
+        """The chunk table of the candidate pair list, cached per size, and
+        the uploads this call made (0 when it was cached)."""
+        return _chunk_table(self.pair_a, self.pair_b, chunk, self._chunk_cache)
+
+    def chunk_tables(self, chunk: int, rows: int) -> List[ChunkTable]:
+        """The candidate pair list as chunk tables of exactly ``rows`` rows
+        (a power of two), uncached.  A program that reads them is keyed by
+        ``rows``, never by the batch's chunk count."""
+        step = rows * chunk
+        return [
+            _chunk_table(
+                self.pair_a[s : s + step], self.pair_b[s : s + step],
+                chunk, {}, rows,
+            )[0]
+            for s in range(0, self.num_pairs, step)
+        ]
 
 
 # ---------------------------------------------------------------------------
@@ -798,12 +840,13 @@ class SelfJoinEngine:
             "engine.count", "join",
             n=snap.num_points, eps=eps, tier=dec.execution,
         ):
-            for pa, pb, real in chunks(eng.count_chunk):
+            table, stats.chunk_table_uploads = chunks(eng.count_chunk)
+            for i in range(table.num):
                 with obs.span("engine.count.chunk", "dispatch"):
                     counts_sorted, skipped_tot = _count_chunk_program(
                         counts_sorted, skipped_tot,
                         tiles, tile_len, tile_start,
-                        pa, pb, real, eps,
+                        table.pa, table.pb, table.real, i, eps,
                         shortc=shortc, backend=backend,
                     )
                 stats.num_chunks += 1
@@ -866,12 +909,13 @@ class SelfJoinEngine:
             "engine.count_query", "join",
             nq=nq, eps=eps, tier=tab.execution,
         ):
-            for pa, pb, real in tab.chunks(eng.count_chunk):
+            table, stats.chunk_table_uploads = tab.chunks(eng.count_chunk)
+            for i in range(table.num):
                 with obs.span("engine.count.chunk", "dispatch"):
                     counts_sorted, skipped_tot = _count_chunk_program(
                         counts_sorted, skipped_tot,
                         tab.tiles, tab.tile_len, tab.tile_start,
-                        pa, pb, real, eps,
+                        table.pa, table.pb, table.real, i, eps,
                         shortc=shortc, backend=backend,
                     )
                 stats.num_chunks += 1
@@ -941,6 +985,7 @@ class SelfJoinEngine:
         retries = 0
         dispatches = 0
         rank_rows = 0
+        uploads = 0
         while True:
             stats = self._base_stats(eps, snap)
             self._record_decision(stats, dec)
@@ -956,12 +1001,14 @@ class SelfJoinEngine:
                 n=snap.num_points, eps=eps, tier=dec.execution,
                 attempt=retries, window=window,
             ):
-                for pa, pb, real in chunks(eng.pairs_chunk):
+                table, up = chunks(eng.pairs_chunk)
+                uploads += up
+                for i in range(table.num):
                     with obs.span("engine.pairs.chunk", "dispatch"):
                         buf, offset, max_hits = _pairs_chunk_program(
                             buf, offset, max_hits,
-                            tiles, tile_len, tile_start,
-                            snap.point_order, pa, pb, real, eps,
+                            tiles, tile_len, tile_start, snap.point_order,
+                            table.pa, table.pb, table.real, i, eps,
                             hit_cap=window, backend=backend,
                         )
                     ends.append(offset)
@@ -989,7 +1036,7 @@ class SelfJoinEngine:
         if int(max_hits) > window:
             buf, extra, rows = self._remainders(
                 buf, ends, window, flat_per_chunk, int(max_hits),
-                chunks(eng.pairs_chunk), tiles, tile_len, tile_start,
+                table, tiles, tile_len, tile_start,
                 snap.point_order, eps, backend,
             )
             retries += 1
@@ -1009,12 +1056,13 @@ class SelfJoinEngine:
         stats.overflow_retries = retries
         stats.num_device_dispatches = dispatches
         stats.rank_rows = rank_rows
+        stats.chunk_table_uploads = uploads
         obs.mirror_selfjoin_stats(stats, path="engine", mode="pairs")
         return SelfJoinResult(counts=counts, stats=stats, pairs=pairs)
 
     @staticmethod
     def _remainders(
-        buf, ends, window, flat_per_chunk, max_hits, chunk_list,
+        buf, ends, window, flat_per_chunk, max_hits, table,
         tiles, tile_len, tile_start, point_order, eps, backend,
     ):
         """Ranks ``window..`` of every chunk that outgrew the first pass's
@@ -1047,12 +1095,14 @@ class SelfJoinEngine:
             zero = jnp.zeros((), jnp.int32)
             for rung, idx in sorted(rungs.items()):
                 for i in idx:
-                    pa, pb, real = chunk_list[i]
-                    obs.event("engine.pairs.remainder.chunk", "dispatch", rung=rung)
+                    obs.event(
+                        "engine.pairs.remainder.chunk", "dispatch",
+                        rung=rung, chunk=i,
+                    )
                     buf, _, _ = _pairs_chunk_program(
                         buf, jnp.int32(end[i] - nh[i] + window), zero,
                         tiles, tile_len, tile_start, point_order,
-                        pa, pb, real, eps,
+                        table.pa, table.pb, table.real, i, eps,
                         hit_cap=rung, backend=backend, first_rank=window,
                     )
                     rows += rung

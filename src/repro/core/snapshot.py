@@ -22,14 +22,20 @@ Padding tile rows carry ``tile_len == 0`` (the sentinel every chunk
 program's validity mask already understands) and are never referenced by a
 candidate pair list, so they contribute zero work and zero results.
 
+Chunk tables (DESIGN.md #1.5): a candidate pair list reaches the device
+as one ``ChunkTable`` per chunk size -- three tables uploaded once, one
+row per chunk, their row count a power-of-two bucket of the chunk count
+-- and the chunk programs read their chunk's row on the device by its
+index.
+
 Nothing here mutates after construction except the two lazy caches (the
-per-chunk-size padded pair lists and the dense tables), both of which are
-pure functions of the frozen state.
+chunk tables of each tier and chunk size, and the dense tables), both of
+which are pure functions of the frozen state.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -53,19 +59,57 @@ from repro.kernels import ops
 _AUTO_PERM = "auto"
 
 
-def _chunk_list(
-    pair_a: np.ndarray, pair_b: np.ndarray, chunk: int, cache: dict
-) -> List[Tuple[jax.Array, jax.Array, int]]:
-    """Padded device chunks of a candidate pair list, cached per chunk size."""
+@dataclasses.dataclass(frozen=True)
+class ChunkTable:
+    """A candidate pair list as device tables, one row per chunk.
+
+    Rows past ``num`` are padding: zero pairs with ``real == 0``, never
+    dispatched.  The row count is ``bucket_rows(num)`` (or the fixed count a
+    caller asks for), so the programs that read a table compile once per
+    bucket.
+    """
+
+    pa: jax.Array    # (rows, C) int32 A-side tile index of each pair
+    pb: jax.Array    # (rows, C) int32 B-side tile index
+    real: jax.Array  # (rows,) int32 real pairs in each row
+    num: int         # chunks holding pairs: the rows a loop dispatches
+
+    @property
+    def rows(self) -> int:
+        return int(self.real.shape[0])
+
+
+CHUNK_TABLE_UPLOADS = 3  # host->device transfers of one ChunkTable
+
+
+def _chunk_table(
+    pair_a: np.ndarray, pair_b: np.ndarray, chunk: int, cache: dict,
+    min_rows: int = 1,
+) -> Tuple[ChunkTable, int]:
+    """The chunk table of a candidate pair list at ``chunk`` pairs a row,
+    cached per chunk size, and the host->device transfers this call made
+    (``CHUNK_TABLE_UPLOADS`` on a miss, 0 on a hit)."""
     got = cache.get(chunk)
-    if got is None:
-        with obs.span("engine.chunk_tables", "transfer", chunk=chunk):
-            got = [
-                (pa, pb, real)
-                for _, pa, pb, real in ops._chunks(pair_a, pair_b, chunk)
-            ]
-        cache[chunk] = got
-    return got
+    if got is not None:
+        return got, 0
+    with obs.span("engine.chunk_tables", "transfer", chunk=chunk):
+        p = int(pair_a.shape[0])
+        num = -(-p // chunk)
+        rows = bucket_rows(num, min_rows)
+
+        def table(pairs):
+            flat = np.zeros(rows * chunk, np.int32)
+            flat[:p] = pairs
+            return jnp.asarray(flat.reshape(rows, chunk))
+
+        real = np.clip(p - np.arange(rows, dtype=np.int64) * chunk, 0, chunk)
+        # int32 on the host: a conversion on the device is one more program
+        got = ChunkTable(
+            table(pair_a), table(pair_b), jnp.asarray(real.astype(np.int32)),
+            num,
+        )
+    cache[chunk] = got
+    return got, CHUNK_TABLE_UPLOADS
 
 
 def make_dense_plan(n_points: int, tile_size: int) -> TilePlan:
@@ -103,11 +147,11 @@ class DenseTables:
     tiles: jax.Array          # (dense_rows, NB, T, DB) f32, sequential layout
     tile_len: jax.Array       # (dense_rows,) int32; padding rows are 0
     tile_start: jax.Array     # (dense_rows,) int32 into pts_sorted
-    _chunk_cache: Dict[int, list] = dataclasses.field(default_factory=dict)
+    _chunk_cache: Dict[int, ChunkTable] = dataclasses.field(default_factory=dict)
 
-    def chunks(self, chunk: int) -> List[Tuple[jax.Array, jax.Array, int]]:
-        return _chunk_list(self.plan.pair_a, self.plan.pair_b, chunk,
-                           self._chunk_cache)
+    def chunks(self, chunk: int) -> Tuple[ChunkTable, int]:
+        return _chunk_table(self.plan.pair_a, self.plan.pair_b, chunk,
+                            self._chunk_cache)
 
 
 class GridSnapshot:
@@ -157,7 +201,7 @@ class GridSnapshot:
             -(-self.num_points // config.tile_size), min_dense_rows
         )
         self._dense: Optional[DenseTables] = None
-        self._chunk_cache: dict = {}
+        self._chunk_cache: Dict[int, ChunkTable] = {}
         if grid is None:
             self.tiles = None
             self.tile_len = None
@@ -306,9 +350,10 @@ class GridSnapshot:
         z = np.zeros(self.num_dims, np.float64)
         return z, z
 
-    def chunks(self, chunk: int) -> List[Tuple[jax.Array, jax.Array, int]]:
-        """Padded device chunks of the self-join candidate pair list."""
-        return _chunk_list(
+    def chunks(self, chunk: int) -> Tuple[ChunkTable, int]:
+        """The chunk table of the self-join candidate pair list, and the
+        uploads this call made (0 when it was cached)."""
+        return _chunk_table(
             self.plan.pair_a, self.plan.pair_b, chunk, self._chunk_cache
         )
 

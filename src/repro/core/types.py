@@ -91,6 +91,8 @@ class SelfJoinStats:
     overflow_retries: int = 0            # auto-grow retries in pairs mode (engine)
     rank_rows: int = 0                   # rank-window rows over every pairs
                                          # dispatch of the join (engine)
+    chunk_table_uploads: int = 0         # host->device transfers of chunk
+                                         # tables (0 when they were cached)
     num_workers: int = 0                 # |p| (distributed engine)
     num_rounds: int = 0                  # ring rounds executed (= |p|)
     worker_pair_cursors: tuple = ()      # per-worker final pairs-buffer cursor

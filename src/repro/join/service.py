@@ -29,11 +29,13 @@ ids (stable across compactions).
 Compilation discipline -- the property that makes this a *service* rather
 than a loop of one-shot joins: request batches are padded to power-of-two
 shape buckets (``SelfJoinEngine.prepare_query(pad_queries_to=...)``), the
-snapshot's data-side tables are padded to its own pow2 row buckets, eps is
-always a traced scalar, and the chunk programs are jitted once per service
-with a host-side trace counter in the traced body, so an arbitrary request
-stream compiles at most one count and one pairs executable per bucket --
-and a snapshot swap of unchanged buckets adds ZERO traces.
+snapshot's data-side tables are padded to its own pow2 row buckets, a
+batch's candidate pairs reach the device as chunk tables of one fixed row
+count, eps is always a traced scalar, and the chunk programs are jitted
+once per service with a host-side trace counter in the traced body, so an
+arbitrary request stream compiles at most one count and one pairs
+executable per bucket -- and a snapshot swap of unchanged buckets adds
+ZERO traces.
 ``ServiceStats.num_traces`` reports it per request and
 ``QueryService.total`` accumulates it across the stream -- the serving
 analogue of the fused ring's ``fused_traces == 1`` contract.
@@ -65,6 +67,7 @@ import numpy as np
 
 from repro.core.engine import (
     QueryPlanTables,
+    chunk_row,
     count_chunk_step,
     hit_window,
     pairs_chunk_step,
@@ -75,6 +78,11 @@ from repro import obs
 from repro.kernels import ops
 
 _MAX_HITCAP_RETRIES = 8
+
+# Rows of every chunk table the service uploads.  One fixed row count keeps
+# the chunk steps' trace key free of a batch's chunk count; a batch of more
+# chunks uploads more tables.
+_TABLE_ROWS = 8
 
 
 @dataclasses.dataclass
@@ -179,8 +187,10 @@ class QueryService:
         # dense/indexed dispatch boundary compiles at most one executable
         # per shape bucket PER TIER (the tile-table shapes differ between
         # tiers anyway, so the tier is already part of the trace key).
+        # Both chunk steps read row ``i`` of a ``_TABLE_ROWS``-row chunk
+        # table on the device.
         def _count_step(
-            counts, tiles, tile_len, tile_start, pa, pb, real, eps,
+            counts, tiles, tile_len, tile_start, pa, pb, real, i, eps,
             *, backend, shortc,
         ):
             self._trace_count += 1
@@ -189,20 +199,21 @@ class QueryService:
             obs.event("service.trace", "trace", program="count")
             counts, _ = count_chunk_step(
                 counts, jnp.zeros((), jnp.int32),
-                tiles, tile_len, tile_start, pa, pb, real, eps,
+                tiles, tile_len, tile_start, *chunk_row(pa, pb, real, i), eps,
                 shortc=shortc, backend=backend,
             )
             return counts
 
         def _pairs_step(
             buf, offset, max_hits, tiles, tile_len, tile_start, order,
-            pa, pb, real, eps, *, hit_cap, backend,
+            pa, pb, real, i, eps, *, hit_cap, backend,
         ):
             self._trace_count += 1
             obs.event("service.trace", "trace", program="pairs")
             return pairs_chunk_step(
                 buf, offset, max_hits, tiles, tile_len, tile_start, order,
-                pa, pb, real, eps, hit_cap=hit_cap, backend=backend,
+                *chunk_row(pa, pb, real, i), eps,
+                hit_cap=hit_cap, backend=backend,
             )
 
         # the delta/tombstone epilogue: one dense bipartite membership pass
@@ -281,15 +292,17 @@ class QueryService:
     ) -> np.ndarray:
         tier = self._tier_kwargs(tab)
         counts_sorted = jnp.zeros(tab.n_slots, jnp.int32)
-        for pa, pb, real in tab.chunks(self._count_chunk):
-            with obs.span(
-                "service.count.chunk", "dispatch", bucket=tab.n_slots
-            ):
-                counts_sorted = self._count_step(
-                    counts_sorted, tab.tiles, tab.tile_len, tab.tile_start,
-                    pa, pb, real, jnp.float32(eps), **tier,
-                )
-            stats.num_device_dispatches += 1
+        for table in tab.chunk_tables(self._count_chunk, _TABLE_ROWS):
+            for i in range(table.num):
+                with obs.span(
+                    "service.count.chunk", "dispatch", bucket=tab.n_slots
+                ):
+                    counts_sorted = self._count_step(
+                        counts_sorted, tab.tiles, tab.tile_len,
+                        tab.tile_start, table.pa, table.pb, table.real, i,
+                        jnp.float32(eps), **tier,
+                    )
+                stats.num_device_dispatches += 1
         stats.num_candidates += tab.num_candidates
         cs = np.asarray(counts_sorted)
         counts = np.zeros(tab.nq, np.int64)
@@ -305,21 +318,24 @@ class QueryService:
         flat_per_chunk = self._pairs_chunk * t * t
         hit_cap = hit_window(0, flat_per_chunk)
         cap = 1 << (max(int(total), 1) - 1).bit_length()  # pow2: bounded trace keys
+        tables = tab.chunk_tables(self._pairs_chunk, _TABLE_ROWS)
         for _ in range(_MAX_HITCAP_RETRIES + 1):
             buf = jnp.zeros((cap + hit_cap, 2), jnp.int32)
             offset = jnp.zeros((), jnp.int32)
             max_hits = jnp.zeros((), jnp.int32)
-            for pa, pb, real in tab.chunks(self._pairs_chunk):
-                with obs.span(
-                    "service.pairs.chunk", "dispatch", bucket=tab.n_slots
-                ):
-                    buf, offset, max_hits = self._pairs_step(
-                        buf, offset, max_hits,
-                        tab.tiles, tab.tile_len, tab.tile_start, tab.order,
-                        pa, pb, real, jnp.float32(eps), hit_cap=hit_cap,
-                        backend=backend,
-                    )
-                stats.num_device_dispatches += 1
+            for table in tables:
+                for i in range(table.num):
+                    with obs.span(
+                        "service.pairs.chunk", "dispatch", bucket=tab.n_slots
+                    ):
+                        buf, offset, max_hits = self._pairs_step(
+                            buf, offset, max_hits,
+                            tab.tiles, tab.tile_len, tab.tile_start,
+                            tab.order, table.pa, table.pb, table.real, i,
+                            jnp.float32(eps), hit_cap=hit_cap,
+                            backend=backend,
+                        )
+                    stats.num_device_dispatches += 1
             if int(max_hits) <= hit_cap:
                 break
             # a single chunk outgrew the rank window: widen to the observed

@@ -129,6 +129,9 @@ def mirror_selfjoin_stats(stats, *, path: str, mode: str) -> None:
     c("selfjoin_rank_rows_total", "pairs rank-window rows dispatched").inc(
         stats.rank_rows, **labels
     )
+    c("selfjoin_chunk_table_uploads_total", "chunk-table host->device transfers").inc(
+        stats.chunk_table_uploads, **labels
+    )
 
 
 def mirror_service_stats(stats, *, kind: str) -> None:

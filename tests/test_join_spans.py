@@ -5,7 +5,7 @@ once where its work runs:
 
   * ``engine.plan.reorder`` / ``.grid`` / ``.tiles`` / ``.place`` nest in
     ``engine.snapshot_build`` (or ``engine.snapshot_rebuild``);
-  * ``engine.chunk_tables`` (the padded chunk uploads) nests in
+  * ``engine.chunk_tables`` (the chunk table's one upload) nests in
     ``engine.count`` / ``engine.pairs`` on a chunk-cache miss only;
   * ``engine.pairs.estimate`` (the result-size sample) precedes
     ``engine.pairs`` in auto-sized mode, ``engine.pairs.fetch`` (the copy
@@ -24,6 +24,7 @@ from repro import obs
 from repro.core import (
     EngineConfig, GridSnapshot, SelfJoinConfig, SelfJoinEngine, self_join,
 )
+from repro.core.snapshot import CHUNK_TABLE_UPLOADS
 
 PLAN = ("engine.plan.reorder", "engine.plan.grid", "engine.plan.tiles",
         "engine.plan.place")
@@ -78,12 +79,18 @@ def test_chunk_tables_only_on_a_cache_miss(mode):
     eng = SelfJoinEngine(_data(), _cfg())
     run = eng.count if mode == "count" else eng.pairs
     with obs.capture() as first:
-        run()
+        miss = run()
     with obs.capture() as second:
-        run()
+        hit = run()
     assert first.span_count("engine.chunk_tables") == 1
     assert second.span_count("engine.chunk_tables") == 0
     assert second.span_count(JOIN[mode]) == 1
+    assert miss.stats.chunk_table_uploads == CHUNK_TABLE_UPLOADS
+    assert hit.stats.chunk_table_uploads == 0
+    for cap, res in ((first, miss), (second, hit)):
+        assert cap.metric(
+            "selfjoin_chunk_table_uploads_total", path="engine"
+        ) == res.stats.chunk_table_uploads
 
 
 def test_no_estimate_with_an_explicit_capacity():

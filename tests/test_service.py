@@ -16,7 +16,8 @@ from oracles import (
     make_dataset,
     pair_set,
 )
-from repro.core import SelfJoinConfig, select_k
+from repro.core import EngineConfig, SelfJoinConfig, select_k
+from repro.core.grid import bucket_rows
 from repro.join import QueryService, SimilarityIndex
 
 
@@ -175,6 +176,50 @@ def test_compile_reuse_contract_mixed_stream():
     for nq in (3, 40, 100, 250):
         svc.range_count(pool[:nq], 0.06)
     assert svc.total.num_traces == before
+
+
+def test_chunk_count_is_not_a_trace_key():
+    """Batches of one query bucket whose chunk counts fall in different
+    power-of-two buckets, some past one table's rows, run one count
+    executable -- before and after a compaction swap that changes the
+    chunk counts again."""
+    d = make_dataset("exponential", 397, 16, seed=50)
+    extra = make_dataset("exponential", 20, 16, seed=51)
+    idx = SimilarityIndex(
+        d, _cfg(0.08), EngineConfig(count_chunk=12, pairs_chunk=12)
+    )
+    svc = QueryService(idx)
+    batches = (d[:33], d[:64], d[10:45], d[:50])
+
+    def num_chunks(q):
+        tab = idx.engine.prepare_query(q, 0.08, pad_queries_to=64)
+        return -(-tab.num_pairs // 12)
+
+    def serve(q, live):
+        res = svc.range_count(q, 0.08)
+        np.testing.assert_array_equal(
+            res.counts, bipartite_counts(q, live, 0.08)
+        )
+        assert res.stats.bucket == 64
+        return num_chunks(q)
+
+    before = [serve(q, d) for q in batches]
+    assert svc.total.num_traces == 1
+    idx.insert(extra)
+    idx.compact()                                  # the swap
+    live = np.concatenate([d, extra])
+    after = [serve(q, live) for q in batches]
+    seen = before + after
+    assert len({bucket_rows(k) for k in seen}) >= 2
+    assert max(seen) > 8 and before != after       # several tables a batch
+    assert svc.total.num_traces == 1
+    assert svc.buckets_used == {64}
+
+    # the pairs step reads the same tables: its answers span them all
+    q = batches[1]
+    res = svc.range_pairs(q, 0.08)
+    np.testing.assert_array_equal(res.counts, bipartite_counts(q, live, 0.08))
+    assert len(res.pairs) == res.counts.sum()
 
 
 def test_pairs_and_knn_trace_keys_are_bounded():
