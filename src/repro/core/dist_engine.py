@@ -14,9 +14,8 @@ separately, into the design the paper actually describes:
     the wire;
   * **the grid index** (``core/grid.py`` / ``core/engine.py``, Secs. 3-4):
     each worker's local join per round runs through ``build_grid`` /
-    ``build_query_tile_plan`` + the chunked tile-evaluation programs of
-    ``SelfJoinEngine.count_query`` -- REORDER, SORTIDU window pruning and
-    SHORTC included.
+    ``build_query_tile_plan`` + the engine's chunk programs -- REORDER,
+    SORTIDU window pruning and SHORTC included.
 
 The last point is the repair this class exists for: the earlier ring driver
 evaluated every (Q_k, E_j) block pair with a dense brute-force matmul,
@@ -32,7 +31,10 @@ per-round tile evaluation is device code.  Two drivers share that contract:
 
   * the **host-driven** BSP loop (default): the schedule re-enters Python
     between rounds, so it runs identically on 1 or 8 simulated devices and
-    serves as the differential oracle for
+    serves as the differential oracle.  It drives no chunk loop of its
+    own: a count block is ``SelfJoinEngine.count_query``, a pairs block
+    the engine's count loop and exact pairs pass (``run_count_chunks`` /
+    ``exact_pairs``), as in the serving tier;
   * the **device-fused** ring (``fused=True``): the per-(worker, round)
     query tile tables and pair lists are packed host-side into uniform
     (fleet-max-padded, sentinel-masked) arrays, the dataset shards' tile
@@ -42,7 +44,8 @@ per-round tile evaluation is device code.  Two drivers share that contract:
     evaluated through the same chunked count step as
     ``SelfJoinEngine.count_query`` (``engine.count_chunk_step``).  One
     trace, one dispatch per join; eps stays a traced scalar so an eps sweep
-    re-executes the same program.
+    re-executes the same program.  Its chunk loop is a ``fori_loop`` in
+    the program, not the host driver.
 
 Fused pairs mode (DESIGN.md #7b): ``self_join_pairs(fused=True)`` runs the
 same one-program ring, but the per-round chunk body is
@@ -79,12 +82,15 @@ from repro.core import batching as batching_mod
 from repro.core.distributed import ring_comm_elements, ring_scan
 from repro.core.engine import (
     _MAX_AUTO_GROW,
+    _PAIRS_HEADROOM,
     SelfJoinEngine,
     _count_chunk_program,
     _pairs_chunk_program,
     count_chunk_step,
-    hit_window,
+    exact_pairs,
+    kernel_choice,
     pairs_chunk_step,
+    run_count_chunks,
 )
 from repro.core.grid import adjacent_cell_pairs, build_grid, pad_axis0
 from repro.core.partition import EntityPartition, assign_dynamic, make_partition
@@ -422,7 +428,7 @@ class DistributedSelfJoinEngine:
 
         axes_t = (self.axes,) if isinstance(self.axes, str) else tuple(self.axes)
         ax = axes_t if len(axes_t) > 1 else axes_t[0]
-        backend = "pallas" if cfg.use_pallas else "jnp"
+        backend, shortc = kernel_choice("indexed", cfg)
         engine_self = self
 
         def local(qt, qstart, qlen, qord, pq, pd, real, dt, dlen, eps_in):
@@ -444,7 +450,7 @@ class DistributedSelfJoinEngine:
                         counts_sorted, jnp.zeros((), jnp.int32),
                         tiles, tlen, tstart,
                         pq[r, c], pd[r, c], real[r, c], eps_in,
-                        shortc=cfg.shortc, backend=backend,
+                        shortc=shortc, backend=backend,
                     )
                     return counts_sorted
 
@@ -492,7 +498,7 @@ class DistributedSelfJoinEngine:
                 counts_s, _ = ops.tile_counts(
                     np.concatenate([qt[k0, r0], dt[j0]], axis=0), len_c,
                     qp.pair_q[sel], qp.pair_d[sel] + max_qt,
-                    eps=eps, shortc=cfg.shortc,
+                    eps=eps, shortc=shortc,
                     backend=backend, chunk=min(n_s, 512),
                 )
                 cand_s = float(
@@ -508,7 +514,7 @@ class DistributedSelfJoinEngine:
             for k in range(p)
         ]
         pairs_cap = batching_mod.suggest_pairs_capacity(
-            max(pairs_est, default=0), eng.pairs_headroom
+            max(pairs_est, default=0), _PAIRS_HEADROOM
         )
 
         # tables go device-resident (with their ring sharding, row-major as
@@ -637,8 +643,7 @@ class DistributedSelfJoinEngine:
         for k in range(self.num_workers):
             counts[pack["q_index"][k]] = out[k, : pack["nq"][k]]
         pairs_total, pairs_eval, candidates = pack["stats"]
-        shard_sizes = np.diff(self.shard_bounds)
-        stats = SelfJoinStats(
+        stats = self._index_stats(SelfJoinStats(
             num_points=self.num_points,
             num_dims=self.num_dims,
             k=min(self.config.k, self.num_dims),
@@ -650,21 +655,9 @@ class DistributedSelfJoinEngine:
             num_candidates=candidates,
             num_chunks=self.num_workers * pack["n_chunks"],
             num_device_dispatches=1,
-            num_candidates_dense=int(
-                sum(
-                    pack["nq"][k] * shard_sizes[j]
-                    for r, sched in enumerate(self.ring_schedule())
-                    for k, j in sched
-                )
-            ),
+            num_candidates_dense=self._dense_candidates(pack["nq"]),
             num_results=int(counts.sum()),
-        )
-        stats.num_tiles = sum(
-            e.snapshot.plan.num_tiles for e in self.shards if e.snapshot.plan
-        )
-        stats.num_nonempty_cells = sum(
-            e.snapshot.grid.num_cells for e in self.shards if e.snapshot.grid
-        )
+        ))
         obs.mirror_selfjoin_stats(stats, path="ring_fused", mode="count")
         return SelfJoinResult(counts=counts, stats=stats)
 
@@ -758,7 +751,7 @@ class DistributedSelfJoinEngine:
                 retries += 1
                 continue
             if max_off > cap:
-                if auto and eng.auto_grow and retries < _MAX_AUTO_GROW:
+                if auto and retries < _MAX_AUTO_GROW:
                     obs.event(
                         "ring.pairs.retry", "retry", kind="capacity",
                         num=max_off, cap=cap,
@@ -819,71 +812,34 @@ class DistributedSelfJoinEngine:
         stats: SelfJoinStats,
     ) -> np.ndarray:
         """Exact (global query id, global data id) pairs of one (Q_k, E_j)
-        block, via the host-driven count-then-pairs pattern of the serving
-        tier: the count pass sizes the buffer exactly, so the pairs pass
-        never overflows (only the per-chunk rank window may widen)."""
-        e = self.shards[j]
-        tab = e.prepare_query(q_pts_k, eps)
+        block: the count pass, then the exact pairs pass it sizes
+        (``engine.exact_pairs``), as in the serving tier."""
+        tab = self.shards[j].prepare_query(q_pts_k, eps)
         if tab is None:
             return np.zeros((0, 2), np.int64)
-        cfg = self.config
-        backend = ops.backend_name(tab.execution, cfg.use_pallas)
-        shortc = cfg.shortc and tab.execution == "indexed"
-
-        counts_sorted = jnp.zeros(tab.n_slots, jnp.int32)
-        skipped = jnp.zeros((), jnp.int32)
-        table, uploads = tab.chunks(eng.count_chunk)
-        stats.chunk_table_uploads += uploads
-        for i in range(table.num):
-            with obs.span(
-                "ring.block.count.chunk", "dispatch", worker=k, shard=j
-            ):
-                counts_sorted, skipped = _count_chunk_program(
-                    counts_sorted, skipped,
-                    tab.tiles, tab.tile_len, tab.tile_start,
-                    table.pa, table.pb, table.real, i, jnp.float32(eps),
-                    shortc=shortc, backend=backend,
-                )
-            stats.num_device_dispatches += 1
+        eps_d = jnp.float32(eps)
+        count_table, uploads = tab.chunks(eng.count_chunk)
+        counts_sorted, _, n_count = run_count_chunks(
+            tab, [count_table],
+            (jnp.zeros(tab.n_slots, jnp.int32), jnp.zeros((), jnp.int32)),
+            eps_d, _count_chunk_program, "ring.block.count.chunk",
+            worker=k, shard=j,
+        )
         total = int(np.asarray(counts_sorted.sum()))
-
-        t = cfg.tile_size
-        flat_per_chunk = eng.pairs_chunk * t * t
-        hit_cap = hit_window(0, flat_per_chunk)
-        cap = 1 << (max(total, 1) - 1).bit_length()  # pow2: bounded trace keys
-        for _ in range(_MAX_AUTO_GROW + 1):
-            buf = jnp.zeros((cap + hit_cap, 2), jnp.int32)
-            offset = jnp.zeros((), jnp.int32)
-            max_hits = jnp.zeros((), jnp.int32)
-            table, uploads = tab.chunks(eng.pairs_chunk)
-            stats.chunk_table_uploads += uploads
-            for i in range(table.num):
-                with obs.span(
-                    "ring.block.pairs.chunk", "dispatch", worker=k, shard=j
-                ):
-                    buf, offset, max_hits = _pairs_chunk_program(
-                        buf, offset, max_hits,
-                        tab.tiles, tab.tile_len, tab.tile_start, tab.order,
-                        table.pa, table.pb, table.real, i, jnp.float32(eps),
-                        hit_cap=hit_cap, backend=backend,
-                    )
-                stats.num_device_dispatches += 1
-                stats.num_chunks += 1
-            if int(max_hits) <= hit_cap:
-                break
-            hit_cap = hit_window(int(max_hits), flat_per_chunk)
-        num = int(offset)
-        if num != total:
-            raise RuntimeError(
-                f"block ({k}, {j}) pairs pass found {num} pairs but the "
-                f"count pass said {total}"
-            )
+        pairs_table, more = tab.chunks(eng.pairs_chunk)
+        blk, n_pairs = exact_pairs(
+            tab, [pairs_table], eps_d, total, _pairs_chunk_program,
+            "ring.block.pairs.chunk", worker=k, shard=j,
+        )
+        stats.chunk_table_uploads += uploads + more
+        stats.num_device_dispatches += n_count + n_pairs
+        stats.num_chunks += n_pairs
         stats.num_tile_pairs_total += tab.qplan.num_tile_pairs_total
         stats.num_tile_pairs_evaluated += tab.num_pairs
         stats.num_candidates += tab.num_candidates
 
-        blk = np.asarray(buf[:num]).astype(np.int64)
-        if num:
+        blk = blk.astype(np.int64)
+        if blk.shape[0]:
             # order decodes A-side to q-row ids, B-side to shard-local ids
             blk[:, 0] = self.worker_query_index(k)[blk[:, 0]]
             blk[:, 1] += self.shard_bounds[j]
@@ -975,7 +931,6 @@ class DistributedSelfJoinEngine:
         )
         q_index = [self.worker_query_index(k) for k in range(self.num_workers)]
         q_points = [self._pts[idx] for idx in q_index]
-        shard_sizes = np.diff(self.shard_bounds)
         for r, round_sched in enumerate(self.ring_schedule()):
             with obs.span(
                 "ring.round", "ring",
@@ -995,19 +950,13 @@ class DistributedSelfJoinEngine:
                     stats.chunk_table_uploads += s.chunk_table_uploads
                     stats.dim_blocks_skipped += s.dim_blocks_skipped
                     stats.dim_blocks_total += s.dim_blocks_total
-                    stats.num_candidates_dense += int(
-                        q_index[k].size * shard_sizes[j]
-                    )
             stats.num_rounds += 1
-        stats.num_tiles = sum(
-            e.snapshot.plan.num_tiles for e in self.shards if e.snapshot.plan
-        )
-        stats.num_nonempty_cells = sum(
-            e.snapshot.grid.num_cells for e in self.shards if e.snapshot.grid
+        stats.num_candidates_dense = self._dense_candidates(
+            [idx.size for idx in q_index]
         )
         stats.num_results = int(counts.sum())
         obs.mirror_selfjoin_stats(stats, path="ring_host", mode="count")
-        return SelfJoinResult(counts=counts, stats=stats)
+        return SelfJoinResult(counts=counts, stats=self._index_stats(stats))
 
     def self_join_pairs(
         self,

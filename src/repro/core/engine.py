@@ -39,12 +39,21 @@ table bucket)** and the Python chunk loop dispatches that same executable
 executables are module-level, shared by every engine instance; a multi-eps
 sweep recompiles nothing.
 
+One host chunk driver runs every host-driven join: ``kernel_choice`` picks
+a tier's (backend, SHORTC), ``run_count_chunks`` is the count loop and
+``exact_pairs`` the count-sized bipartite pairs pass.  The engine's own
+joins, the host-driven ring's blocks (``core/dist_engine.py``) and the
+serving tier (``repro.join.QueryService``, with its own trace-counted
+programs) all call them; only the self-join's pairs mode keeps its
+estimate-sized window, capacity regrow and remainder pass
+(``SelfJoinEngine.pairs``).
+
 ``repro.core.selfjoin.self_join`` is a thin wrapper over this class.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import functools
 
@@ -62,13 +71,7 @@ from repro.core.grid import (
     pad_axis0,
 )
 from repro.core.reorder import apply_reorder
-from repro.core.snapshot import (  # noqa: F401  (re-exported compat names)
-    ChunkTable,
-    DenseTables,
-    GridSnapshot,
-    _chunk_table,
-    make_dense_plan,
-)
+from repro.core.snapshot import ChunkTable, GridSnapshot, _chunk_table
 from repro.core.types import (
     EngineConfig,
     SelfJoinConfig,
@@ -78,7 +81,8 @@ from repro.core.types import (
 from repro import obs
 from repro.kernels import ops
 
-_MAX_AUTO_GROW = 8  # doublings before giving up on an auto-sized buffer
+_MAX_AUTO_GROW = 8  # regrows (or window widenings) before a pairs pass gives up
+_PAIRS_HEADROOM = 2.0  # auto pairs capacity = headroom * estimated |R|
 _MIN_HIT_WINDOW = 4096  # narrowest rank window a pairs chunk program runs
 # Auto-mode first rank window: the rung at or above this many times the
 # size estimate's mean hits per chunk.  Syn64D2M at eps 0.18 (a v5e, four
@@ -96,6 +100,16 @@ def hit_window(n: int, flat_per_chunk: int) -> int:
     flag).  Powers of two keep the compiled pairs programs few."""
     pow2 = 1 << max(int(n) - 1, 0).bit_length()
     return min(flat_per_chunk, max(_MIN_HIT_WINDOW, pow2))
+
+
+def kernel_choice(execution: str, cfg: SelfJoinConfig) -> Tuple[str, bool]:
+    """``(backend, shortc)`` of the chunk programs for a tier (``"indexed"``
+    | ``"dense"``): the Pallas kernels or their XLA twins per
+    ``cfg.use_pallas``; SHORTC only on the indexed tier (the dense backends
+    skip no dimension block)."""
+    if execution == "dense":
+        return ("dense" if cfg.use_pallas else "dense_jnp"), False
+    return ("pallas" if cfg.use_pallas else "jnp"), cfg.shortc
 
 
 # ---------------------------------------------------------------------------
@@ -323,17 +337,21 @@ def _unsort_counts(counts_sorted, point_order):
 class QueryPlanTables:
     """Device-ready combined (query | data) tables for one bipartite batch.
 
-    Produced by ``SelfJoinEngine.prepare_query`` and consumed by three
+    Produced by ``SelfJoinEngine.prepare_query`` and consumed by the chunk
+    driver below (``run_count_chunks`` / ``exact_pairs``) for three
     callers with one layout:
 
-      * ``SelfJoinEngine.count_query`` (the distributed tier's per-round
-        local join) runs the count chunk program over it;
-      * the serving tier (``repro.join.QueryService``) runs its own
-        trace-counted count *and* pairs programs over it, with
-        ``pad_queries_to`` rounding the query side up to a shape bucket so a
-        request stream reuses a bounded set of executables;
-      * the fused distributed packer keeps its own padding, but shares the
-        underlying ``build_query_plan`` host plan.
+      * ``SelfJoinEngine.count_query`` runs the count chunk program over it;
+      * the host-driven ring's (worker, shard) blocks run the count program
+        and then the exact pairs pass over it;
+      * the serving tier (``repro.join.QueryService``) runs the same two
+        with its own trace-counted programs, with ``pad_queries_to``
+        rounding the query side up to a shape bucket so a request stream
+        reuses a bounded set of executables.
+
+    The fused distributed packer keeps its own padding, but shares the
+    underlying ``build_query_plan`` host plan.  ``backend`` / ``shortc``
+    are the tier's kernel choice (``kernel_choice``).
 
     Layout contract: positions ``[0, n_slots)`` are query rows in q-sorted
     order (real rows first, zero padding after), positions ``[n_slots,
@@ -356,6 +374,8 @@ class QueryPlanTables:
     order: jax.Array               # (n_slots + point_rows,) int32 position -> id
     pair_a: np.ndarray             # (P,) int32 combined-table A (query-tile) index
     pair_b: np.ndarray             # (P,) int32 combined-table B (data-tile) index
+    backend: str                   # chunk programs' backend for this tier
+    shortc: bool                   # SHORTC on (indexed tier only)
     execution: str = "indexed"     # tier the tables realize: "indexed" | "dense"
     cost_indexed: float = 0.0      # cost model's indexed-tier estimate
     cost_dense: float = 0.0        # cost model's dense-tier estimate
@@ -383,6 +403,117 @@ class QueryPlanTables:
             )[0]
             for s in range(0, self.num_pairs, step)
         ]
+
+
+class SelfTables(NamedTuple):
+    """The device tables of the tier a self-join runs (``_self_tables``):
+    the same attributes the chunk driver reads off ``QueryPlanTables``."""
+
+    tiles: jax.Array
+    tile_len: jax.Array
+    tile_start: jax.Array
+    order: jax.Array       # grid-sorted position -> original id
+    plan: TilePlan
+    chunks: Callable[[int], Tuple[ChunkTable, int]]  # size -> (table, uploads)
+    backend: str
+    shortc: bool
+
+
+# ---------------------------------------------------------------------------
+# The host chunk driver: the one loop that dispatches chunk programs, for
+# the engine's joins, the host-driven ring's blocks and the serving tier.
+# ``tab`` is a ``SelfTables`` or a ``QueryPlanTables``; ``program`` is the
+# engine's jitted chunk program or a caller's own (the service counts its
+# traces), with the same positional signature.
+# ---------------------------------------------------------------------------
+
+
+def run_count_chunks(tab, tables, state, eps, program, span, **attrs):
+    """Every row of every chunk table in ``tables`` through the count
+    ``program``, from ``state`` = (counts, skipped-block total).  Returns
+    the counts, the skipped total and the dispatches made."""
+    counts, skipped = state
+    tiles, tile_len, tile_start = tab.tiles, tab.tile_len, tab.tile_start
+    backend, shortc = tab.backend, tab.shortc
+    dispatches = 0
+    for table in tables:
+        pa, pb, real = table.pa, table.pb, table.real
+        for i in range(table.num):
+            with obs.span(span, "dispatch", **attrs):
+                counts, skipped = program(
+                    counts, skipped, tiles, tile_len, tile_start,
+                    pa, pb, real, i, eps, shortc=shortc, backend=backend,
+                )
+        dispatches += table.num
+    return counts, skipped, dispatches
+
+
+def run_pairs_chunks(tab, tables, state, eps, program, hit_cap, span, **attrs):
+    """Every row of ``tables`` through the pairs ``program`` at rank window
+    ``hit_cap``, from ``state`` = (buffer, offset, max chunk hits).
+    Returns the state and each chunk's end offset, left on the device."""
+    buf, offset, max_hits = state
+    tiles, tile_len, tile_start = tab.tiles, tab.tile_len, tab.tile_start
+    order, backend = tab.order, tab.backend
+    ends = []
+    for table in tables:
+        pa, pb, real = table.pa, table.pb, table.real
+        for i in range(table.num):
+            with obs.span(span, "dispatch", **attrs):
+                buf, offset, max_hits = program(
+                    buf, offset, max_hits, tiles, tile_len, tile_start,
+                    order, pa, pb, real, i, eps,
+                    hit_cap=hit_cap, backend=backend,
+                )
+            ends.append(offset)
+    return (buf, offset, max_hits), ends
+
+
+def exact_pairs(tab, tables, eps, total, program, span, **attrs):
+    """The exact bipartite pairs pass: all ``total`` pairs a count pass
+    found over ``tab``, as an ``(total, 2)`` host array, and the dispatches
+    made.
+
+    The buffer holds the power of two at or above ``total``, so it never
+    overflows.  The rank window starts at the 4,096 floor; when a chunk
+    outgrew it, the window widens to that chunk's rung and the whole pass
+    runs again (a ``<span prefix>.retry`` instant, ``kind="hit_cap"``).
+    ``tables`` holds at least one table (its width gives the chunk size).
+    """
+    flat_per_chunk = tables[0].pa.shape[1] * tab.tiles.shape[2] ** 2
+    hit_cap = hit_window(0, flat_per_chunk)
+    cap = 1 << (max(total, 1) - 1).bit_length()  # pow2: bounded trace keys
+    dispatches = 0
+    for _ in range(_MAX_AUTO_GROW + 1):
+        state = (
+            jnp.zeros((cap + hit_cap, 2), jnp.int32),
+            jnp.zeros((), jnp.int32),
+            jnp.zeros((), jnp.int32),
+        )
+        (buf, offset, max_hits), ends = run_pairs_chunks(
+            tab, tables, state, eps, program, hit_cap, span, **attrs
+        )
+        dispatches += len(ends)
+        max_hits = int(max_hits)
+        if max_hits <= hit_cap:
+            break
+        obs.event(
+            span.rsplit(".", 1)[0] + ".retry", "retry", kind="hit_cap",
+            max_hits=max_hits, hit_cap=hit_cap, **attrs,
+        )
+        hit_cap = hit_window(max_hits, flat_per_chunk)
+    else:
+        raise RuntimeError(
+            f"pairs rank window did not converge (max chunk hits "
+            f"{max_hits} > hit_cap {hit_cap})"
+        )
+    num = int(offset)
+    if num != total:
+        raise RuntimeError(
+            f"pairs pass found {num} pairs but the count pass said {total}"
+            f" ({span} {attrs})"
+        )
+    return np.asarray(buf[:num]), dispatches
 
 
 # ---------------------------------------------------------------------------
@@ -505,7 +636,7 @@ class SelfJoinEngine:
             ):
                 self.swap_snapshot(snap.rebuilt(eps))
 
-    # -- delegating views (compat surface over the snapshot) ---------------
+    # -- views of the snapshot --------------------------------------------
 
     @property
     def num_points(self) -> int:
@@ -527,38 +658,6 @@ class SelfJoinEngine:
     def n_pad(self) -> int:
         """Padded dimension count of the tile layout (n -> dim_block multiple)."""
         return self.snapshot.n_pad
-
-    @property
-    def _pts(self) -> np.ndarray:
-        return self.snapshot.pts
-
-    @property
-    def _perm(self) -> Optional[np.ndarray]:
-        return self.snapshot.perm
-
-    @property
-    def _index_eps(self) -> Optional[float]:
-        return self.snapshot.index_eps
-
-    @property
-    def _tiles(self) -> jax.Array:
-        return self.snapshot.tiles
-
-    @property
-    def _tile_len(self) -> jax.Array:
-        return self.snapshot.tile_len
-
-    @property
-    def _tile_start(self) -> jax.Array:
-        return self.snapshot.tile_start
-
-    @property
-    def _point_order(self) -> jax.Array:
-        return self.snapshot.point_order
-
-    @property
-    def _num_dim_blocks(self) -> int:
-        return self.snapshot.num_dim_blocks
 
     def resolve_execution(
         self, eps: Optional[float] = None,
@@ -603,11 +702,19 @@ class SelfJoinEngine:
             stats.num_candidates_dense = snap.num_points * snap.num_points
         return stats
 
-    @staticmethod
-    def _record_decision(stats: SelfJoinStats, dec: cost_mod.TierDecision) -> None:
+    def _self_stats(
+        self, eps: float, snap: GridSnapshot, dec: cost_mod.TierDecision,
+        plan: TilePlan,
+    ) -> SelfJoinStats:
+        """Stats of a self-join on the tier ``dec`` chose, whose pair list
+        is ``plan``'s."""
+        stats = self._base_stats(eps, snap)
         stats.execution = dec.execution
         stats.cost_indexed = dec.cost_indexed
         stats.cost_dense = dec.cost_dense
+        stats.num_tile_pairs_evaluated = plan.num_pairs
+        stats.num_candidates = plan.num_candidates
+        return stats
 
     def build_query_plan(
         self,
@@ -769,6 +876,7 @@ class SelfJoinEngine:
             ]
         )
         pair_b = (pair_d + qt_rows).astype(np.int32)
+        backend, shortc = kernel_choice(dec.execution, cfg)
         return QueryPlanTables(
             eps=eps,
             nq=nq,
@@ -780,37 +888,29 @@ class SelfJoinEngine:
             order=order,
             pair_a=pair_a.astype(np.int32),
             pair_b=pair_b,
+            backend=backend,
+            shortc=shortc,
             execution=dec.execution,
             cost_indexed=dec.cost_indexed,
             cost_dense=dec.cost_dense,
             num_candidates=num_candidates,
         )
 
-    def packed_tile_table(self, num_tiles: int):
-        """Host tile table padded to ``num_tiles`` rows (delegates to the
-        snapshot; kept for callers that hold only the engine)."""
-        return self.snapshot.packed_tile_table(num_tiles)
-
     # -- queries ----------------------------------------------------------
 
-    def _self_tables(self, dec: cost_mod.TierDecision, snap: GridSnapshot):
-        """Device tables of the tier ``dec`` chose, one tuple for both modes.
+    def _self_tables(
+        self, dec: cost_mod.TierDecision, snap: GridSnapshot
+    ) -> SelfTables:
+        """Device tables of the tier ``dec`` chose, for both modes.
 
-        Returns ``(tiles, tile_len, tile_start, chunks_fn, plan, backend,
-        shortc)``.  Both tiers address the same grid-sorted point space (the
-        dense tier only re-tiles it), so the scatter epilogues and
+        Both tiers address the same grid-sorted point space (the dense tier
+        only re-tiles it), so ``order``, the scatter epilogues and
         ``_unsort_counts`` are tier-independent.
         """
-        cfg = self.config
-        if dec.execution == "dense":
-            dt = snap.dense_tables()
-            return (
-                dt.tiles, dt.tile_len, dt.tile_start, dt.chunks, dt.plan,
-                ops.backend_name("dense", cfg.use_pallas), False,
-            )
-        return (
-            snap.tiles, snap.tile_len, snap.tile_start, snap.chunks,
-            snap.plan, ops.backend_name("indexed", cfg.use_pallas), cfg.shortc,
+        tier = snap.dense_tables() if dec.execution == "dense" else snap
+        return SelfTables(
+            tier.tiles, tier.tile_len, tier.tile_start, snap.point_order,
+            tier.plan, tier.chunks, *kernel_choice(dec.execution, self.config),
         )
 
     def count(self, eps: Optional[float] = None) -> SelfJoinResult:
@@ -823,40 +923,27 @@ class SelfJoinEngine:
             )
         self._ensure_index(eps)
         snap = self.snapshot
-        cfg, eng = self.config, self.engine
         dec = self.resolve_execution(eps)
-        tiles, tile_len, tile_start, chunks, plan, backend, shortc = (
-            self._self_tables(dec, snap)
-        )
-        stats = self._base_stats(eps, snap)
-        self._record_decision(stats, dec)
-        if dec.execution == "dense":
-            stats.num_tile_pairs_evaluated = plan.num_pairs
-            stats.num_candidates = plan.num_candidates
+        tab = self._self_tables(dec, snap)
+        stats = self._self_stats(eps, snap, dec, tab.plan)
 
-        counts_sorted = jnp.zeros(snap.num_points, jnp.int32)
-        skipped_tot = jnp.zeros((), jnp.int32)
+        state = (jnp.zeros(snap.num_points, jnp.int32), jnp.zeros((), jnp.int32))
         with obs.span(
             "engine.count", "join",
             n=snap.num_points, eps=eps, tier=dec.execution,
         ):
-            table, stats.chunk_table_uploads = chunks(eng.count_chunk)
-            for i in range(table.num):
-                with obs.span("engine.count.chunk", "dispatch"):
-                    counts_sorted, skipped_tot = _count_chunk_program(
-                        counts_sorted, skipped_tot,
-                        tiles, tile_len, tile_start,
-                        table.pa, table.pb, table.real, i, eps,
-                        shortc=shortc, backend=backend,
-                    )
-                stats.num_chunks += 1
-                stats.num_device_dispatches += 1
+            table, stats.chunk_table_uploads = tab.chunks(self.engine.count_chunk)
+            counts_sorted, skipped_tot, stats.num_chunks = run_count_chunks(
+                tab, [table], state, eps, _count_chunk_program,
+                "engine.count.chunk",
+            )
+            stats.num_device_dispatches = stats.num_chunks
             counts = np.asarray(
                 _unsort_counts(counts_sorted, snap.point_order)
             ).astype(np.int64)
         stats.num_results = int(counts.sum())
         stats.dim_blocks_skipped = int(skipped_tot)
-        stats.dim_blocks_total = plan.num_pairs * snap.num_dim_blocks
+        stats.dim_blocks_total = tab.plan.num_pairs * snap.num_dim_blocks
         obs.mirror_selfjoin_stats(stats, path="engine", mode="count")
         return SelfJoinResult(counts=counts, stats=stats)
 
@@ -880,7 +967,6 @@ class SelfJoinEngine:
         eps = self.config.eps if eps is None else float(eps)
         q_pts = np.ascontiguousarray(np.asarray(q, dtype=np.float32))
         nq = q_pts.shape[0]
-        cfg, eng = self.config, self.engine
         tab = self.prepare_query(q_pts, eps, snapshot=snapshot)
         snap = snapshot if snapshot is not None else self.snapshot
         if tab is None:
@@ -900,26 +986,17 @@ class SelfJoinEngine:
         stats.execution = tab.execution
         stats.cost_indexed = tab.cost_indexed
         stats.cost_dense = tab.cost_dense
-        backend = ops.backend_name(tab.execution, cfg.use_pallas)
-        shortc = cfg.shortc and tab.execution == "indexed"
-
-        counts_sorted = jnp.zeros(tab.n_slots, jnp.int32)
-        skipped_tot = jnp.zeros((), jnp.int32)
+        state = (jnp.zeros(tab.n_slots, jnp.int32), jnp.zeros((), jnp.int32))
         with obs.span(
             "engine.count_query", "join",
             nq=nq, eps=eps, tier=tab.execution,
         ):
-            table, stats.chunk_table_uploads = tab.chunks(eng.count_chunk)
-            for i in range(table.num):
-                with obs.span("engine.count.chunk", "dispatch"):
-                    counts_sorted, skipped_tot = _count_chunk_program(
-                        counts_sorted, skipped_tot,
-                        tab.tiles, tab.tile_len, tab.tile_start,
-                        table.pa, table.pb, table.real, i, eps,
-                        shortc=shortc, backend=backend,
-                    )
-                stats.num_chunks += 1
-                stats.num_device_dispatches += 1
+            table, stats.chunk_table_uploads = tab.chunks(self.engine.count_chunk)
+            counts_sorted, skipped_tot, stats.num_chunks = run_count_chunks(
+                tab, [table], state, eps, _count_chunk_program,
+                "engine.count.chunk",
+            )
+            stats.num_device_dispatches = stats.num_chunks
             counts = np.asarray(
                 _unsort_counts(
                     counts_sorted, jnp.asarray(qplan.q_order, jnp.int32)
@@ -961,9 +1038,8 @@ class SelfJoinEngine:
         snap = self.snapshot
         cfg, eng = self.config, self.engine
         dec = self.resolve_execution(eps)
-        tiles, tile_len, tile_start, chunks, plan, backend, _ = (
-            self._self_tables(dec, snap)
-        )
+        tab = self._self_tables(dec, snap)
+        plan = tab.plan
 
         explicit = max_pairs if max_pairs is not None else eng.max_pairs
         auto = explicit is None
@@ -976,7 +1052,7 @@ class SelfJoinEngine:
             cap = int(_cap_hint)
         else:
             est = self._estimate(eps, dec) if _est_hint is None else _est_hint
-            cap = batching_mod.suggest_pairs_capacity(est, eng.pairs_headroom)
+            cap = batching_mod.suggest_pairs_capacity(est, _PAIRS_HEADROOM)
             num_chunks = max(1, -(-plan.num_pairs // eng.pairs_chunk))
             window = hit_window(
                 -(-_WINDOW_PER_MEAN * est // num_chunks), flat_per_chunk
@@ -986,40 +1062,33 @@ class SelfJoinEngine:
         dispatches = 0
         rank_rows = 0
         uploads = 0
+        stats = self._self_stats(eps, snap, dec, plan)
         while True:
-            stats = self._base_stats(eps, snap)
-            self._record_decision(stats, dec)
-            if dec.execution == "dense":
-                stats.num_tile_pairs_evaluated = plan.num_pairs
-                stats.num_candidates = plan.num_candidates
-            buf = jnp.zeros((cap + window, 2), jnp.int32)
-            offset = jnp.zeros((), jnp.int32)
-            max_hits = jnp.zeros((), jnp.int32)
-            ends = []  # each chunk's end offset, left on the device
+            state = (
+                jnp.zeros((cap + window, 2), jnp.int32),
+                jnp.zeros((), jnp.int32),
+                jnp.zeros((), jnp.int32),
+            )
             with obs.span(
                 "engine.pairs", "join",
                 n=snap.num_points, eps=eps, tier=dec.execution,
                 attempt=retries, window=window,
             ):
-                table, up = chunks(eng.pairs_chunk)
+                table, up = tab.chunks(eng.pairs_chunk)
                 uploads += up
-                for i in range(table.num):
-                    with obs.span("engine.pairs.chunk", "dispatch"):
-                        buf, offset, max_hits = _pairs_chunk_program(
-                            buf, offset, max_hits,
-                            tiles, tile_len, tile_start, snap.point_order,
-                            table.pa, table.pb, table.real, i, eps,
-                            hit_cap=window, backend=backend,
-                        )
-                    ends.append(offset)
-                    stats.num_chunks += 1
-                    dispatches += 1
+                # ends: each chunk's end offset, left on the device
+                (buf, offset, max_hits), ends = run_pairs_chunks(
+                    tab, [table], state, eps, _pairs_chunk_program, window,
+                    "engine.pairs.chunk",
+                )
+                stats.num_chunks = len(ends)
+                dispatches += len(ends)
                 rank_rows += stats.num_chunks * window
                 num = int(offset)
             # exact |R| is known after a full pass: (auto mode) regrow the
             # buffer to it and run the join again
             if num > cap:
-                if auto and eng.auto_grow and retries < _MAX_AUTO_GROW:
+                if auto and retries < _MAX_AUTO_GROW:
                     obs.event(
                         "engine.pairs.retry", "retry", kind="capacity",
                         num=num, cap=cap,
@@ -1035,9 +1104,8 @@ class SelfJoinEngine:
 
         if int(max_hits) > window:
             buf, extra, rows = self._remainders(
-                buf, ends, window, flat_per_chunk, int(max_hits),
-                table, tiles, tile_len, tile_start,
-                snap.point_order, eps, backend,
+                tab, table, buf, ends, window, flat_per_chunk, int(max_hits),
+                eps,
             )
             retries += 1
             dispatches += extra
@@ -1062,8 +1130,7 @@ class SelfJoinEngine:
 
     @staticmethod
     def _remainders(
-        buf, ends, window, flat_per_chunk, max_hits, table,
-        tiles, tile_len, tile_start, point_order, eps, backend,
+        tab, table, buf, ends, window, flat_per_chunk, max_hits, eps
     ):
         """Ranks ``window..`` of every chunk that outgrew the first pass's
         rank window, written into the gap the pass left for them.
@@ -1101,9 +1168,9 @@ class SelfJoinEngine:
                     )
                     buf, _, _ = _pairs_chunk_program(
                         buf, jnp.int32(end[i] - nh[i] + window), zero,
-                        tiles, tile_len, tile_start, point_order,
+                        tab.tiles, tab.tile_len, tab.tile_start, tab.order,
                         table.pa, table.pb, table.real, i, eps,
-                        hit_cap=rung, backend=backend, first_rank=window,
+                        hit_cap=rung, backend=tab.backend, first_rank=window,
                     )
                     rows += rung
             buf.block_until_ready()  # the span holds the pass's device time
@@ -1117,14 +1184,11 @@ class SelfJoinEngine:
         the chosen backend, so it reflects the tables that will actually
         run.
         """
-        cfg = self.config
         with obs.span("engine.pairs.estimate", "plan", eps=eps):
-            tiles, tile_len, _, _, plan, backend, _ = self._self_tables(
-                dec, self.snapshot
-            )
+            tab = self._self_tables(dec, self.snapshot)
             return batching_mod.estimate_result_size(
-                tiles, tile_len, plan, eps=eps, backend=backend,
-                sample_frac=cfg.sample_frac,
+                tab.tiles, tab.tile_len, tab.plan, eps=eps,
+                backend=tab.backend, sample_frac=self.config.sample_frac,
             )
 
     def query(

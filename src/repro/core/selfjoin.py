@@ -32,7 +32,7 @@ from typing import List, Optional
 import numpy as np
 
 from repro.core import batching as batching_mod
-from repro.core.engine import SelfJoinEngine
+from repro.core.engine import SelfJoinEngine, kernel_choice
 from repro.core.grid import build_grid, build_tile_plan
 from repro.core.reorder import variance_reorder
 from repro.core.types import SelfJoinConfig, SelfJoinResult, SelfJoinStats
@@ -87,7 +87,7 @@ def self_join_hostloop(
         grid.pts_sorted, plan.tile_start, plan.tile_len,
         config.tile_size, config.dim_block,
     )
-    backend = "pallas" if config.use_pallas else "jnp"
+    backend, shortc = kernel_choice("indexed", config)
     nb_blocks = tiles_pts.shape[1]
 
     counts_sorted = np.zeros(n_pts, dtype=np.int64)
@@ -127,7 +127,7 @@ def self_join_hostloop(
     else:
         counts_pair, skipped = ops.tile_counts(
             tiles_pts, tile_len, plan.pair_a, plan.pair_b,
-            eps=config.eps, shortc=config.shortc, backend=backend,
+            eps=config.eps, shortc=shortc, backend=backend,
         )
         pa = plan.pair_a
         idx = plan.tile_start[pa].astype(np.int64)[:, None] + lane[None, :]

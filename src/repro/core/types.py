@@ -60,9 +60,7 @@ class EngineConfig:
     count_chunk: int = 4096      # tile pairs per counts-mode device program
     pairs_chunk: int = 1024      # tile pairs per pairs-mode device program
     max_pairs: Optional[int] = None  # pairs-buffer capacity; None -> auto-size
-    auto_grow: bool = True       # on auto-sized overflow, regrow to the
-                                 # measured |R| (known after the pass) and retry
-    pairs_headroom: float = 2.0  # auto capacity = headroom * estimated |R|
+                                     # (regrown on overflow), else overflow raises
 
     def __post_init__(self):
         if self.count_chunk < 1 or self.pairs_chunk < 1:

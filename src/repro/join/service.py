@@ -69,15 +69,13 @@ from repro.core.engine import (
     QueryPlanTables,
     chunk_row,
     count_chunk_step,
-    hit_window,
+    exact_pairs,
     pairs_chunk_step,
+    run_count_chunks,
 )
 from repro.core.grid import pad_axis0
 from repro.join.index import IndexView, SimilarityIndex
 from repro import obs
-from repro.kernels import ops
-
-_MAX_HITCAP_RETRIES = 8
 
 # Rows of every chunk table the service uploads.  One fixed row count keeps
 # the chunk steps' trace key free of a batch's chunk count; a batch of more
@@ -188,21 +186,21 @@ class QueryService:
         # per shape bucket PER TIER (the tile-table shapes differ between
         # tiers anyway, so the tier is already part of the trace key).
         # Both chunk steps read row ``i`` of a ``_TABLE_ROWS``-row chunk
-        # table on the device.
+        # table on the device, with the engine's programs' signatures, so
+        # the engine's chunk driver runs them.
         def _count_step(
-            counts, tiles, tile_len, tile_start, pa, pb, real, i, eps,
-            *, backend, shortc,
+            counts, skipped, tiles, tile_len, tile_start, pa, pb, real, i,
+            eps, *, shortc, backend,
         ):
             self._trace_count += 1
             # the "trace" obs category fires exactly when _trace_count
             # increments, so trace-span count == ServiceStats.num_traces
             obs.event("service.trace", "trace", program="count")
-            counts, _ = count_chunk_step(
-                counts, jnp.zeros((), jnp.int32),
-                tiles, tile_len, tile_start, *chunk_row(pa, pb, real, i), eps,
+            return count_chunk_step(
+                counts, skipped, tiles, tile_len, tile_start,
+                *chunk_row(pa, pb, real, i), eps,
                 shortc=shortc, backend=backend,
             )
-            return counts
 
         def _pairs_step(
             buf, offset, max_hits, tiles, tile_len, tile_start, order,
@@ -280,77 +278,21 @@ class QueryService:
             stats.record_tier(tab.execution, tab.cost_indexed, tab.cost_dense)
         return tab
 
-    def _tier_kwargs(self, tab: QueryPlanTables) -> dict:
-        cfg = self.index.config
-        return {
-            "backend": ops.backend_name(tab.execution, cfg.use_pallas),
-            "shortc": cfg.shortc and tab.execution == "indexed",
-        }
-
     def _run_counts(
         self, tab: QueryPlanTables, eps: float, stats: ServiceStats
     ) -> np.ndarray:
-        tier = self._tier_kwargs(tab)
-        counts_sorted = jnp.zeros(tab.n_slots, jnp.int32)
-        for table in tab.chunk_tables(self._count_chunk, _TABLE_ROWS):
-            for i in range(table.num):
-                with obs.span(
-                    "service.count.chunk", "dispatch", bucket=tab.n_slots
-                ):
-                    counts_sorted = self._count_step(
-                        counts_sorted, tab.tiles, tab.tile_len,
-                        tab.tile_start, table.pa, table.pb, table.real, i,
-                        jnp.float32(eps), **tier,
-                    )
-                stats.num_device_dispatches += 1
+        counts_sorted, _, dispatches = run_count_chunks(
+            tab, tab.chunk_tables(self._count_chunk, _TABLE_ROWS),
+            (jnp.zeros(tab.n_slots, jnp.int32), jnp.zeros((), jnp.int32)),
+            jnp.float32(eps), self._count_step, "service.count.chunk",
+            bucket=tab.n_slots,
+        )
+        stats.num_device_dispatches += dispatches
         stats.num_candidates += tab.num_candidates
         cs = np.asarray(counts_sorted)
         counts = np.zeros(tab.nq, np.int64)
         counts[tab.qplan.q_order] = cs[: tab.nq]
         return counts
-
-    def _run_pairs(
-        self, tab: QueryPlanTables, eps: float, total: int, stats: ServiceStats
-    ) -> np.ndarray:
-        """One pairs pass sized exactly from the known count total."""
-        t = int(self.index.config.tile_size)
-        backend = self._tier_kwargs(tab)["backend"]
-        flat_per_chunk = self._pairs_chunk * t * t
-        hit_cap = hit_window(0, flat_per_chunk)
-        cap = 1 << (max(int(total), 1) - 1).bit_length()  # pow2: bounded trace keys
-        tables = tab.chunk_tables(self._pairs_chunk, _TABLE_ROWS)
-        for _ in range(_MAX_HITCAP_RETRIES + 1):
-            buf = jnp.zeros((cap + hit_cap, 2), jnp.int32)
-            offset = jnp.zeros((), jnp.int32)
-            max_hits = jnp.zeros((), jnp.int32)
-            for table in tables:
-                for i in range(table.num):
-                    with obs.span(
-                        "service.pairs.chunk", "dispatch", bucket=tab.n_slots
-                    ):
-                        buf, offset, max_hits = self._pairs_step(
-                            buf, offset, max_hits,
-                            tab.tiles, tab.tile_len, tab.tile_start,
-                            tab.order, table.pa, table.pb, table.real, i,
-                            jnp.float32(eps), hit_cap=hit_cap,
-                            backend=backend,
-                        )
-                    stats.num_device_dispatches += 1
-            if int(max_hits) <= hit_cap:
-                break
-            # a single chunk outgrew the rank window: widen to the observed
-            # maximum (pow2 so the retry shapes stay bounded) and redo
-            obs.event(
-                "service.pairs.retry", "retry", kind="hit_cap",
-                max_hits=int(max_hits), hit_cap=hit_cap,
-            )
-            hit_cap = hit_window(int(max_hits), flat_per_chunk)
-        num = int(offset)
-        if num != total:
-            raise RuntimeError(
-                f"pairs pass found {num} pairs but the count pass said {total}"
-            )
-        return np.asarray(buf[:num])
 
     def _aux_mask(
         self,
@@ -412,42 +354,36 @@ class QueryService:
         delta_mask: Optional[np.ndarray],
         stats: ServiceStats,
     ) -> np.ndarray:
-        """Materialized (query row, GLOBAL id) pairs of the live set."""
-        with obs.span("service.epilogue", "service", eps=eps):
-            return self._global_pairs_impl(
-                eps, tab, view, snap_counts, delta_mask, stats
-            )
-
-    def _global_pairs_impl(
-        self,
-        eps: float,
-        tab: Optional[QueryPlanTables],
-        view: IndexView,
-        snap_counts: np.ndarray,
-        delta_mask: Optional[np.ndarray],
-        stats: ServiceStats,
-    ) -> np.ndarray:
+        """Materialized (query row, GLOBAL id) pairs of the live set: the
+        snapshot's exact pairs pass (sized by its count total), tombstoned
+        rows dropped, delta matches merged."""
         parts = []
         snap_total = int(snap_counts.sum())
-        if tab is not None and snap_total:
-            sp = self._run_pairs(tab, eps, snap_total, stats)
-            if view.tombstone_count:
-                sp = sp[~np.isin(sp[:, 1], view.dead_rows)]
-            if sp.shape[0]:
-                parts.append(np.column_stack(
-                    [sp[:, 0].astype(np.int64), view.snap_ids[sp[:, 1]]]
-                ))
-        if delta_mask is not None:
-            qr, j = np.nonzero(delta_mask)
-            if qr.size:
-                parts.append(np.column_stack(
-                    [qr.astype(np.int64), view.delta_ids[j]]
-                ))
-        if not parts:
-            return np.zeros((0, 2), np.int64)
-        pairs = np.concatenate(parts)
-        srt = np.lexsort((pairs[:, 1], pairs[:, 0]))
-        return np.ascontiguousarray(pairs[srt])
+        with obs.span("service.epilogue", "service", eps=eps):
+            if tab is not None and snap_total:
+                sp, dispatches = exact_pairs(
+                    tab, tab.chunk_tables(self._pairs_chunk, _TABLE_ROWS),
+                    jnp.float32(eps), snap_total, self._pairs_step,
+                    "service.pairs.chunk", bucket=tab.n_slots,
+                )
+                stats.num_device_dispatches += dispatches
+                if view.tombstone_count:
+                    sp = sp[~np.isin(sp[:, 1], view.dead_rows)]
+                if sp.shape[0]:
+                    parts.append(np.column_stack(
+                        [sp[:, 0].astype(np.int64), view.snap_ids[sp[:, 1]]]
+                    ))
+            if delta_mask is not None:
+                qr, j = np.nonzero(delta_mask)
+                if qr.size:
+                    parts.append(np.column_stack(
+                        [qr.astype(np.int64), view.delta_ids[j]]
+                    ))
+            if not parts:
+                return np.zeros((0, 2), np.int64)
+            pairs = np.concatenate(parts)
+            srt = np.lexsort((pairs[:, 1], pairs[:, 0]))
+            return np.ascontiguousarray(pairs[srt])
 
     def _finish(
         self, stats: ServiceStats, traces_before: int, kind: str

@@ -13,7 +13,7 @@ DESIGN.md #9):
     ``max(|a|^2 + |b|^2 - 2 a.b^T, 0)`` (``ref.matmul_sqdist``).
   * ``backend="dense_jnp"`` -- the XLA twin of the dense kernel.
 
-``backend_name(tier, use_pallas)`` maps an execution tier to its backend
+``repro.core.engine.kernel_choice`` maps an execution tier to its backend
 string; the dense backends ignore ``shortc`` and report 0 skipped blocks.
 
 Tile layout: every tile table is ``(num_tiles, NB, T, dim_block)`` f32 --
@@ -58,13 +58,6 @@ BACKENDS = ("pallas", "jnp", "dense", "dense_jnp")
 # f32 operands to bf16, which moves squared distances by ~1e-3 relative --
 # far more than the eps boundary tolerates (DESIGN.md #6)
 HIGHEST = jax.lax.Precision.HIGHEST
-
-
-def backend_name(execution: str, use_pallas: bool) -> str:
-    """Backend string for an execution tier (``"indexed"`` | ``"dense"``)."""
-    if execution == "dense":
-        return "dense" if use_pallas else "dense_jnp"
-    return "pallas" if use_pallas else "jnp"
 
 
 def block_dims(x, dim_block: int):

@@ -82,9 +82,9 @@ def test_dist_engine_shards_share_the_dataset_reorder_frame():
     for j in range(4):
         d[200 * j : 200 * (j + 1), j] *= 5 + j
     de = DistributedSelfJoinEngine(d, SelfJoinConfig(eps=0.05, k=3), num_workers=4)
-    want = SelfJoinEngine(d, SelfJoinConfig(eps=0.05, k=3))._perm
+    want = SelfJoinEngine(d, SelfJoinConfig(eps=0.05, k=3)).snapshot.perm
     for shard in de.shards:
-        assert np.array_equal(shard._perm, want)
+        assert np.array_equal(shard.snapshot.perm, want)
     assert np.array_equal(de.count().counts, brute_counts(d, 0.05))
 
 

@@ -179,8 +179,8 @@ def test_capacity_estimate_never_underallocates(d, eps):
     cfg = SelfJoinConfig(eps=eps, k=3, tile_size=8, dim_block=8)
     eng = SelfJoinEngine(d, cfg)
     est = batching.estimate_result_size(
-        np.asarray(eng._tiles), np.asarray(eng._tile_len), eng.plan,
-        eps=eps, backend="jnp", sample_frac=1.0,
+        np.asarray(eng.snapshot.tiles), np.asarray(eng.snapshot.tile_len),
+        eng.plan, eps=eps, backend="jnp", sample_frac=1.0,
     )
     true_r = int(brute_counts(d, eps).sum())
     assert est >= true_r

@@ -9,7 +9,9 @@ every rank window it must write exactly what the flat ``cumsum`` +
 A remainder block (first rank > 0) writes only the chunk's ranks and
 leaves every other row of the buffer as it was.  A join whose chunks
 outgrow the first rank window must come out identical, in order, to one
-pass at a window that holds every chunk.
+pass at a window that holds every chunk.  The exact bipartite pass that the
+serving tier and the host-driven ring share widens its window instead, and
+must come out exact too.
 """
 import functools
 
@@ -20,8 +22,14 @@ import pytest
 
 from oracles import brute_pairs, make_dataset, pair_set
 from repro import obs
-from repro.core import EngineConfig, SelfJoinConfig, SelfJoinEngine
+from repro.core import (
+    DistributedSelfJoinEngine,
+    EngineConfig,
+    SelfJoinConfig,
+    SelfJoinEngine,
+)
 from repro.core import engine as engine_mod
+from repro.join import QueryService, SimilarityIndex
 
 C, T = 128, 16
 NUM_TILES = 40
@@ -182,6 +190,40 @@ def test_pairs_across_hit_cap_retry(n, eps, chunk, sizing, compiles):
     else:
         assert window > 4096
     assert pair_set(res.pairs) == pair_set(brute_pairs(d, eps))
+
+
+def _served_pairs(d, cfg, eng_cfg):
+    svc = QueryService(SimilarityIndex(d, cfg, eng_cfg))
+    res = svc.range_pairs(d)  # the dataset as queries: row i is point i
+    return res.pairs, res.counts, svc.range_count(d).counts
+
+
+def _host_ring_pairs(d, cfg, eng_cfg):
+    de = DistributedSelfJoinEngine(
+        d, cfg, num_workers=2, engine_config=eng_cfg
+    )
+    res = de.self_join_pairs(fused=False)
+    return res.pairs, res.counts, de.count().counts
+
+
+@pytest.mark.parametrize(
+    "run, retry",
+    [(_served_pairs, "service.pairs.retry"),
+     (_host_ring_pairs, "ring.block.pairs.retry")],
+    ids=["service", "host_ring"],
+)
+def test_exact_bipartite_pass_widens(run, retry):
+    # T = 8, C = 528: bipartite chunks of this clustered set hold up to
+    # ~4.8k hits, past the 4,096-rank first window of the exact pass
+    d = make_dataset("clustered", 1400, 4, seed=3)
+    cfg = SelfJoinConfig(eps=0.3, k=4, tile_size=8)
+    with obs.capture() as cap:
+        pairs, counts, count_pass = run(d, cfg, EngineConfig(pairs_chunk=528))
+    widened = cap.spans(retry)
+    assert widened and all(e.attrs["kind"] == "hit_cap" for e in widened)
+    assert max(e.attrs["max_hits"] for e in widened) > 4096
+    assert pair_set(pairs) == pair_set(brute_pairs(d, cfg.eps))
+    np.testing.assert_array_equal(counts, count_pass)
 
 
 # (first rank, rank window, hit density): a remainder block's ranks start
